@@ -21,6 +21,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     from benchmarks.bench_ablation import bench_table2
     from benchmarks.bench_cacheopt import bench_table3
     from benchmarks.bench_compute import bench_compute

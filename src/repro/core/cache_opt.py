@@ -391,9 +391,12 @@ def allocate_memory_bytes(
             c = _round_to(opt_items[d.tenant], shape_grain) + int(
                 extra_b // bpi[d.tenant]
             )
-            alloc_items[d.tenant] = min(
-                _round_to(c, shape_grain), d.n_items
-            )
+            # snap DOWN (never below the rounded optimum, itself a grain
+            # multiple): rounding up could overshoot the budget, as in
+            # _water_fill
+            if shape_grain > 1:
+                c = (c // shape_grain) * shape_grain
+            alloc_items[d.tenant] = min(c, d.n_items)
     else:
         alloc_items = _water_fill(demands, opt_items, usable, shape_grain)
 

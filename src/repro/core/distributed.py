@@ -40,16 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# shard_map moved (experimental → jax.shard_map) and renamed its
-# replication-check kwarg (check_rep → check_vma) across JAX releases;
-# resolve whichever this installation provides.
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
 from repro.core import search as S
 from repro.core.graph import HNSWGraph
 from repro.core.hnsw import build_hnsw
@@ -262,12 +252,12 @@ def make_distributed_search(
         )
 
     ispec = P(data_axes)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         local_program,
         mesh=mesh,
         in_specs=(qspec, ispec, ispec, ispec, ispec, ispec, ispec, ispec),
         out_specs=(qspec, qspec),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
 
     def search_fn(Q, index: ShardedIndex):
@@ -331,8 +321,8 @@ class ShardedEngineState:
     with rows padded past ``n`` marked tombstoned.
     """
 
-    table: jnp.ndarray  # (S, rows, d) payload — f32, or int8/f16 quantized
-    scales: jnp.ndarray  # (S, rows) f32 dequant scales (int8); (S, 1) dummy
+    table: jnp.ndarray  # (S, rows_pad, d) payload — f32, or int8/f16
+    scales: jnp.ndarray  # (S, rows_pad) f32 int8 scales; (S, 1) dummy
     neighbors: jnp.ndarray  # (S, L, rows, deg) int32 GLOBAL-id adjacency
     tombstones: jnp.ndarray  # (S, rows) bool — padding rows True
     n: int  # global id-space size
@@ -342,10 +332,6 @@ class ShardedEngineState:
     @property
     def n_shards(self) -> int:
         return int(self.table.shape[0])
-
-    @property
-    def rows(self) -> int:
-        return int(self.table.shape[1])
 
 
 def build_sharded_engine_state(
@@ -364,20 +350,26 @@ def build_sharded_engine_state(
     tier-3 reads shard-local) and quantized per shard; the int8/f16
     codec is per-row (``quant.quantize_np``), so per-shard quantization
     is bit-identical to quantizing the whole table at once.
+
+    Each shard's payload rows are padded to a multiple of
+    ``TABLE_ROW_ALIGN`` so the gather kernels read the table in place;
+    the padding rows are never addressed (ownership follows ``rows``).
     """
     from repro.core import quant
     from repro.core.graph import PAD
     from repro.core.storage import mesh_shard_ranges
+    from repro.kernels.gather_distance import TABLE_ROW_ALIGN
 
     n_shards = mesh.shape["shard"]
     L, n, deg = neighbors.shape
     d = backend.dim
     rows = -(-n // n_shards)
+    rows_pad = -(-rows // TABLE_ROW_ALIGN) * TABLE_ROW_ALIGN
     pay_dtype = {"int8": np.int8, "float16": np.float16,
                  "float32": np.float32}[precision]
-    table = np.zeros((n_shards, rows, d), pay_dtype)
+    table = np.zeros((n_shards, rows_pad, d), pay_dtype)
     scales = np.zeros(
-        (n_shards, rows if precision == "int8" else 1), np.float32
+        (n_shards, rows_pad if precision == "int8" else 1), np.float32
     )
     for s, (lo, hi) in enumerate(mesh_shard_ranges(n, n_shards)):
         if hi <= lo:
@@ -559,10 +551,10 @@ def sharded_layer_program(
         return bi, bd, be, hops, nd
 
     rep, shd = P(), P("shard")
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         program,
         mesh=mesh,
         in_specs=(rep, rep, shd, shd, shd, shd),
         out_specs=(rep, rep, rep, rep, rep),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     ))

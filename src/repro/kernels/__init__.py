@@ -8,7 +8,7 @@ the pure-jnp oracle):
 - gather_distance.py fused scalar-prefetch gather + distance (ANNS hot path)
 - dequant_gather_distance.py
                      the quantized twin: int8/f16 rows + per-row scales
-                     dequantized in-kernel, ~4x less HBM traffic (§7)
+                     dequantized in-kernel, ~4x less HBM held (§7)
 - embedding_bag.py   fused gather-accumulate embedding bag (recsys)
 """
 

@@ -1,16 +1,14 @@
 """Fused dequant + gather + distance Pallas kernels (DESIGN.md §7).
 
 The quantized twin of ``gather_distance.py``: the table rows live in HBM
-as int8 (or float16) with one float32 scale per row, and each grid step
-DMAs ONE quantized row-block plus its scale into VMEM, dequantizes in
-registers, and emits the distance contribution — no float32 copy of the
-table (or even of the gathered rows) is ever materialized. Bytes moved
-per distance evaluation drop ~4× vs the float32 kernel, which is the
-whole point: the ANNS hot path is memory-bound, so the dequant is free.
-
-Same scalar-prefetch idiom as the float32 kernels: the id list sits in
-SMEM ahead of the grid; each step's BlockSpec ``index_map`` reads
-``ids[i]`` to select the table row-block AND the matching scale block.
+as int8 (or float16) with one float32 scale per row. The row gather is
+the same kernel (``gather_distance.gather_rows_distance``): each id's
+quantized row tile is DMA'd into VMEM, the row is dequantized there,
+and only distances leave the kernel — no float32 copy of the table (or
+even of the gathered rows) is ever materialized in HBM. The per-row
+scales are gathered by XLA in the wrapper (4 bytes per id). A row tile
+is ``32·d`` bytes whatever the dtype, so this kernel DMAs as many bytes
+per id as the float32 one; what quantization saves is HBM capacity.
 
 Metrics: 'l2' and 'ip' as usual. 'cos' normalizes the query in the
 wrapper and divides by the gathered row's norm in-kernel (normalizing
@@ -24,25 +22,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-
-def _dqgd_kernel(ids_ref, q_ref, scale_ref, row_ref, o_ref, *, metric: str):
-    """Grid = (n_ids,). row_ref holds table[ids[i]] (1, d) and scale_ref
-    holds scales[ids[i]] (1,) — both selected via index_map."""
-    i = pl.program_id(0)
-    x = row_ref[...].astype(jnp.float32) * scale_ref[0]  # dequant in VMEM
-    q = q_ref[...].astype(jnp.float32)  # (1, d)
-    if metric == "l2":
-        diff = x - q
-        d = jnp.sum(diff * diff)
-    elif metric == "cos":  # q pre-normalized by the wrapper
-        d = -jnp.sum(x * q) / (jnp.sqrt(jnp.sum(x * x)) + 1e-30)
-    else:  # 'ip'
-        d = -jnp.sum(x * q)
-    valid = ids_ref[i] >= 0
-    o_ref[0] = jnp.where(valid, d, jnp.inf)
+from repro.kernels.gather_distance import gather_rows_distance
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "interpret"))
@@ -52,60 +33,12 @@ def dequant_gather_distance_pallas(
     ids: jnp.ndarray,  # (B,) int32, -1 padded
     q: jnp.ndarray,  # (d,) float32
     metric: str = "l2",
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Distances (B,) of dequantized table[ids] to q; +inf for padding."""
-    N, d = table.shape
-    B = ids.shape[0]
-    if metric == "cos":
-        q = q / (jnp.linalg.norm(q) + 1e-30)
-    raw_ids = ids.astype(jnp.int32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda i, ids_ref: (0, 0)),  # q (broadcast)
-            # clip in the index_map so the DMA stays in-bounds while the
-            # kernel body can still test validity (id >= 0)
-            pl.BlockSpec(
-                (1,), lambda i, ids_ref: (jnp.maximum(ids_ref[i], 0),)
-            ),
-            pl.BlockSpec(
-                (1, d), lambda i, ids_ref: (jnp.maximum(ids_ref[i], 0), 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i, ids_ref: (i,)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_dqgd_kernel, metric=metric),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.float32),
-        interpret=interpret,
-    )(raw_ids, q[None, :], scales.astype(jnp.float32), table)
-    return jnp.where(ids >= 0, out, jnp.inf)
-
-
-# ----------------------------------------------------------- batched form
-
-
-def _dqgd_batch_kernel(
-    ids_ref, q_ref, scale_ref, row_ref, o_ref, *, metric: str
-):
-    """Grid = (B, K). row/scale refs hold table[ids[b, i]] and its scale;
-    q_ref holds Q[b] — all selected by their index_maps."""
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    x = row_ref[...].astype(jnp.float32) * scale_ref[0]
-    q = q_ref[...].astype(jnp.float32)  # (1, d)
-    if metric == "l2":
-        diff = x - q
-        d = jnp.sum(diff * diff)
-    elif metric == "cos":  # Q pre-normalized by the wrapper
-        d = -jnp.sum(x * q) / (jnp.sqrt(jnp.sum(x * x)) + 1e-30)
-    else:  # 'ip'
-        d = -jnp.sum(x * q)
-    valid = ids_ref[b, i] >= 0
-    o_ref[0, 0] = jnp.where(valid, d, jnp.inf)
+    return gather_rows_distance(
+        table, scales, ids[None, :], q[None, :], metric, interpret
+    )[0]
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "interpret"))
@@ -115,36 +48,9 @@ def dequant_gather_distance_batch_pallas(
     ids: jnp.ndarray,  # (B, K) int32, -1 padded — per-query miss lists
     Q: jnp.ndarray,  # (B, d) — one query per id row
     metric: str = "l2",
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Batched fused dequant + gather + distance: (B, K) ids × (B, d)
-    queries → (B, K) float32 distances, +inf for padded ids. One
-    quantized-row DMA per (query, slot) — nothing materialized at
-    (B, K, d), in any dtype."""
-    N, d = table.shape
-    B, K = ids.shape
-    if metric == "cos":
-        Q = Q / (jnp.linalg.norm(Q, axis=-1, keepdims=True) + 1e-30)
-    raw_ids = ids.astype(jnp.int32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, K),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda b, i, ids_ref: (b, 0)),  # Q[b]
-            pl.BlockSpec(
-                (1,), lambda b, i, ids_ref: (jnp.maximum(ids_ref[b, i], 0),)
-            ),
-            pl.BlockSpec(
-                (1, d),
-                lambda b, i, ids_ref: (jnp.maximum(ids_ref[b, i], 0), 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, i, ids_ref: (b, i)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_dqgd_batch_kernel, metric=metric),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K), jnp.float32),
-        interpret=interpret,
-    )(raw_ids, Q, scales.astype(jnp.float32), table)
-    return jnp.where(ids >= 0, out, jnp.inf)
+    queries → (B, K) float32 distances, +inf for padded ids. Nothing
+    is materialized at (B, K, d), in any dtype."""
+    return gather_rows_distance(table, scales, ids, Q, metric, interpret)

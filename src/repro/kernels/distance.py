@@ -72,7 +72,7 @@ def distance_matrix_pallas(
     tq: int = DEF_TQ,
     tn: int = DEF_TN,
     td: int = DEF_TD,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Returns (B, N) f32 distances. Pads all dims to tile multiples.
 

@@ -42,7 +42,7 @@ def embedding_bag_pallas(
     table: jnp.ndarray,  # (V, d)
     idx: jnp.ndarray,  # (B, S) int32, -1 padded
     combiner: str = "sum",
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     V, d = table.shape
     B, S = idx.shape

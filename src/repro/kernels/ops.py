@@ -1,14 +1,14 @@
 """Jitted public wrappers over the Pallas kernels, with backend dispatch.
 
-On TPU (the target) these route to the Pallas kernels. On the CPU host
-(this container) Pallas only *interprets* — correct but slow to compile at
-production grids — so by default the mathematically-identical jnp
-reference path runs instead, keeping the multi-pod dry-run's HLO clean
-and compile times sane. Kernel-vs-ref equivalence is enforced by the
-sweep tests in ``tests/test_kernels.py`` (interpret mode), so the dispatch
-is behavior-preserving.
+On TPU (the target) these route to the compiled Pallas kernels. On any
+other backend Pallas can only *interpret* — correct but slow to compile
+at production grids — so the mathematically-identical jnp reference
+path runs instead, keeping the multi-pod dry-run's HLO clean and compile
+times sane. Kernel-vs-ref equivalence is enforced by the sweep tests in
+``tests/test_kernels.py`` (interpret mode), and the kernels' TPU
+lowering by ``tests/test_tpu_compile.py``.
 
-Set ``REPRO_FORCE_PALLAS=1`` to force the interpret-mode kernels off-TPU.
+Set ``REPRO_FORCE_PALLAS=1`` to run the interpret-mode kernels off-TPU.
 """
 
 from __future__ import annotations
@@ -37,19 +37,20 @@ from repro.kernels.gather_distance import (
 from repro.kernels.topk import merge_topk_pallas, topk_pallas
 
 
-def _use_pallas() -> bool:
+def _pallas_mode():
+    """``None`` routes to the jnp reference; otherwise the ``interpret``
+    flag for the Pallas kernel (False on TPU: compiled)."""
+    if jax.default_backend() == "tpu":
+        return False
     if os.environ.get("REPRO_FORCE_PALLAS") == "1":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return None
 
 
 def distance_matrix(Q: jnp.ndarray, X: jnp.ndarray, metric: str = "l2"):
     """(B, d) × (N, d) → (B, N) f32 distances."""
-    if _use_pallas():
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None:
         return distance_matrix_pallas(Q, X, metric=metric, interpret=interp)
     return ref.distance_matrix_ref(Q, X, metric)
 
@@ -60,8 +61,8 @@ def distance_topk_ready(Q, X, metric: str = "l2"):
 
 
 def topk(D: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if _use_pallas():
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None:
         return topk_pallas(D, k, interpret=interp)
     return ref.topk_ref(D, k)
 
@@ -71,8 +72,8 @@ def merge_topk(dists: jnp.ndarray, ids: jnp.ndarray, k: int):
     surfacing from several shards), drop sentinels (id < 0 / non-finite
     dist), return the k smallest as (dists, ids, src) with beam_merge's
     lowest-input-position tie break (DESIGN.md §10)."""
-    if _use_pallas():
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None:
         return merge_topk_pallas(dists, ids, k, interpret=interp)
     return ref.merge_topk_ref(dists, ids, k)
 
@@ -83,8 +84,8 @@ def distance_topk(Q, X, k: int, metric: str = "l2"):
 
 
 def gather_distance(table, ids, q, metric: str = "l2"):
-    if _use_pallas():
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None:
         return gather_distance_pallas(table, ids, q, metric=metric,
                                       interpret=interp)
     return ref.gather_distance_ref(table, ids, q, metric)
@@ -92,8 +93,8 @@ def gather_distance(table, ids, q, metric: str = "l2"):
 
 def gather_distance_batch(table, ids, Q, metric: str = "l2"):
     """(B, K) ids × (B, d) queries → (B, K) distances (batched lazy load)."""
-    if _use_pallas():
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None:
         return gather_distance_batch_pallas(table, ids, Q, metric=metric,
                                             interpret=interp)
     return ref.gather_distance_batch_ref(table, ids, Q, metric)
@@ -102,8 +103,8 @@ def gather_distance_batch(table, ids, Q, metric: str = "l2"):
 def dequant_gather_distance(table, scales, ids, q, metric: str = "l2"):
     """Quantized-table fused gather + distance: (N, d) int8/f16 payload
     with (N,) per-row scales → (B,) f32 distances (DESIGN.md §7)."""
-    if _use_pallas():
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None:
         return dequant_gather_distance_pallas(
             table, scales, ids, q, metric=metric, interpret=interp)
     return ref.dequant_gather_distance_ref(table, scales, ids, q, metric)
@@ -112,8 +113,8 @@ def dequant_gather_distance(table, scales, ids, q, metric: str = "l2"):
 def dequant_gather_distance_batch(table, scales, ids, Q, metric: str = "l2"):
     """Batched quantized-table fused gather + distance: (B, K) ids ×
     (B, d) queries → (B, K) f32 distances (batched lazy load, §7)."""
-    if _use_pallas():
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None:
         return dequant_gather_distance_batch_pallas(
             table, scales, ids, Q, metric=metric, interpret=interp)
     return ref.dequant_gather_distance_batch_ref(table, scales, ids, Q,
@@ -124,8 +125,8 @@ def adc_gather_distance(codes, lut, ids, metric: str = "l2"):
     """PQ-coded fused code-gather + LUT-accumulate (ADC): (N, M) uint8
     codes × an (L, M, 256) per-query table → (B,) f32 distances
     (DESIGN.md §12). Build the table with ``repro.core.pq.build_lut_*``."""
-    if _use_pallas():
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None:
         return adc_gather_distance_pallas(
             codes, lut, ids, metric=metric, interpret=interp)
     return ref.adc_gather_distance_ref(codes, lut, ids, metric)
@@ -134,16 +135,16 @@ def adc_gather_distance(codes, lut, ids, metric: str = "l2"):
 def adc_gather_distance_batch(codes, luts, ids, metric: str = "l2"):
     """Batched ADC: (B, K) ids × (B, L, M, 256) per-query tables →
     (B, K) f32 distances (batched lazy load, §12)."""
-    if _use_pallas():
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None:
         return adc_gather_distance_batch_pallas(
             codes, luts, ids, metric=metric, interpret=interp)
     return ref.adc_gather_distance_batch_ref(codes, luts, ids, metric)
 
 
 def embedding_bag(table, idx, weights=None, combiner: str = "sum"):
-    if _use_pallas() and weights is None:
-        interp = jax.default_backend() != "tpu"
+    interp = _pallas_mode()
+    if interp is not None and weights is None:
         return embedding_bag_pallas(table, idx, combiner=combiner,
                                     interpret=interp)
     return ref.embedding_bag_ref(table, idx, weights, combiner)

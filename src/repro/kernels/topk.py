@@ -9,7 +9,8 @@ candidates to (B, n_tiles·k); a cheap final ``lax.top_k`` merge over the
 (n_tiles·k) survivors happens in the jitted wrapper. Total work drops from
 O(N log N) sort to O(N·k/TN + T·k log(T·k)).
 
-VMEM: (TB=128, TN=512) f32 tile = 256 KiB + out (128, k≤64) ≈ 32 KiB.
+VMEM: (TB=128, TN=512) f32 tile = 256 KiB + two (128, 128) outputs (k
+padded to a lane multiple) = 128 KiB.
 """
 
 from __future__ import annotations
@@ -22,28 +23,44 @@ from jax.experimental import pallas as pl
 
 DEF_TB = 128
 DEF_TN = 512
+LANES = 128
+
+
+def _first_index(hit, col, width: int):
+    """(TB, 1) lowest column where ``hit`` holds (argmin's tie break)."""
+    return jnp.min(jnp.where(hit, col, width), axis=1, keepdims=True)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _topk_tile_kernel(d_ref, od_ref, oi_ref, *, k: int, tn: int):
-    """Select k smallest in this (TB, TN) tile via iterative extraction."""
+    """Select k smallest in this (TB, TN) tile via iterative extraction.
+
+    Extraction ``i`` lands in output lane ``i`` by an iota-compare
+    select (Mosaic has no dynamic_update_slice); the output block is
+    ``kp`` = k rounded up to 128 lanes, sliced back by the wrapper."""
     j = pl.program_id(1)
     d = d_ref[...].astype(jnp.float32)  # (TB, TN)
     tb = d.shape[0]
+    kp = od_ref.shape[1]
     col = jax.lax.broadcasted_iota(jnp.int32, (tb, tn), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, kp), 1)
     base = j * tn
 
     def body(i, carry):
         d_cur, od, oi = carry
-        m = jnp.min(d_cur, axis=1)  # (TB,)
-        am = jnp.argmin(d_cur, axis=1).astype(jnp.int32)  # (TB,)
-        od = jax.lax.dynamic_update_index_in_dim(od, m, i, 1)
-        oi = jax.lax.dynamic_update_index_in_dim(oi, am + base, i, 1)
+        m = jnp.min(d_cur, axis=1, keepdims=True)  # (TB, 1)
+        am = _first_index(d_cur == m, col, tn)  # (TB, 1)
+        od = jnp.where(lane == i, m, od)
+        oi = jnp.where(lane == i, am + base, oi)
         # mask out the extracted element
-        d_cur = jnp.where(col == am[:, None], jnp.inf, d_cur)
+        d_cur = jnp.where(col == am, jnp.inf, d_cur)
         return d_cur, od, oi
 
-    od0 = jnp.full((tb, k), jnp.inf, jnp.float32)
-    oi0 = jnp.full((tb, k), -1, jnp.int32)
+    od0 = jnp.full((tb, kp), jnp.inf, jnp.float32)
+    oi0 = jnp.full((tb, kp), -1, jnp.int32)
     _, od, oi = jax.lax.fori_loop(0, k, body, (d, od0, oi0))
     od_ref[...] = od
     oi_ref[...] = oi
@@ -57,7 +74,7 @@ def topk_pallas(
     k: int,
     tb: int = DEF_TB,
     tn: int = DEF_TN,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Row-wise smallest-k: returns (dists (B, k), ids (B, k))."""
     B, N = D.shape
@@ -65,23 +82,27 @@ def topk_pallas(
     pn = (-N) % tn
     Dp = jnp.pad(D, ((0, pb), (0, pn)), constant_values=jnp.inf)
     nb, nn = Dp.shape[0] // tb, Dp.shape[1] // tn
+    kp = _round_up(k, LANES)
     od, oi = pl.pallas_call(
         functools.partial(_topk_tile_kernel, k=k, tn=tn),
         out_shape=(
-            jax.ShapeDtypeStruct((Dp.shape[0], nn * k), jnp.float32),
-            jax.ShapeDtypeStruct((Dp.shape[0], nn * k), jnp.int32),
+            jax.ShapeDtypeStruct((Dp.shape[0], nn * kp), jnp.float32),
+            jax.ShapeDtypeStruct((Dp.shape[0], nn * kp), jnp.int32),
         ),
         grid=(nb, nn),
         in_specs=[pl.BlockSpec((tb, tn), lambda i, j: (i, j))],
         out_specs=(
-            pl.BlockSpec((tb, k), lambda i, j: (i, j)),
-            pl.BlockSpec((tb, k), lambda i, j: (i, j)),
+            pl.BlockSpec((tb, kp), lambda i, j: (i, j)),
+            pl.BlockSpec((tb, kp), lambda i, j: (i, j)),
         ),
+        name="topk",
         interpret=interpret,
     )(Dp)
     # final merge over nn*k survivors per row (cheap)
-    negd, sel = jax.lax.top_k(-od[:B], k)
-    ids = jnp.take_along_axis(oi[:B], sel, axis=1)
+    od = od[:B].reshape(B, nn, kp)[:, :, :k].reshape(B, nn * k)
+    oi = oi[:B].reshape(B, nn, kp)[:, :, :k].reshape(B, nn * k)
+    negd, sel = jax.lax.top_k(-od, k)
+    ids = jnp.take_along_axis(oi, sel, axis=1)
     return -negd, ids
 
 
@@ -102,33 +123,31 @@ def _merge_topk_kernel(d_ref, i_ref, od_ref, oi_ref, os_ref, *, k: int):
     d = d_ref[...].astype(jnp.float32)  # (TB, M)
     ids = i_ref[...]  # (TB, M)
     tb, m = d.shape
-    d = jnp.where((ids >= 0) & jnp.isfinite(d), d, jnp.inf)
+    kp = od_ref.shape[1]
+    # |d| < inf is false for ±inf and nan alike
+    d = jnp.where((ids >= 0) & (jnp.abs(d) < jnp.inf), d, jnp.inf)
     col = jax.lax.broadcasted_iota(jnp.int32, (tb, m), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, kp), 1)
 
     def body(i, carry):
         d_cur, od, oi, osrc = carry
-        mn = jnp.min(d_cur, axis=1)  # (TB,)
-        am = jnp.argmin(d_cur, axis=1).astype(jnp.int32)  # (TB,)
-        sel = col == am[:, None]
+        mn = jnp.min(d_cur, axis=1, keepdims=True)  # (TB, 1)
+        am = _first_index(d_cur == mn, col, m)  # (TB, 1)
+        sel = col == am
         # exactly one column matches → sum pulls out ids[am] (VPU-friendly
         # one-hot gather; per-row dynamic indexing is TPU-hostile)
-        v = jnp.sum(jnp.where(sel, ids, 0), axis=1).astype(jnp.int32)
+        v = jnp.sum(jnp.where(sel, ids, 0), axis=1, keepdims=True)
         ok = mn < jnp.inf
-        od = jax.lax.dynamic_update_index_in_dim(
-            od, jnp.where(ok, mn, jnp.inf), i, 1
-        )
-        oi = jax.lax.dynamic_update_index_in_dim(
-            oi, jnp.where(ok, v, -1), i, 1
-        )
-        osrc = jax.lax.dynamic_update_index_in_dim(
-            osrc, jnp.where(ok, am, -1), i, 1
-        )
+        at = lane == i
+        od = jnp.where(at, jnp.where(ok, mn, jnp.inf), od)
+        oi = jnp.where(at, jnp.where(ok, v, -1), oi)
+        osrc = jnp.where(at, jnp.where(ok, am, -1), osrc)
         # retire the winner and every duplicate of its id
-        hit = sel | (ok[:, None] & (ids == v[:, None]))
+        hit = sel | (ok & (ids == v))
         return jnp.where(hit, jnp.inf, d_cur), od, oi, osrc
 
-    od0 = jnp.full((tb, k), jnp.inf, jnp.float32)
-    oi0 = jnp.full((tb, k), -1, jnp.int32)
+    od0 = jnp.full((tb, kp), jnp.inf, jnp.float32)
+    oi0 = jnp.full((tb, kp), -1, jnp.int32)
     _, od, oi, osrc = jax.lax.fori_loop(0, k, body, (d, od0, oi0, oi0))
     od_ref[...] = od
     oi_ref[...] = oi
@@ -141,7 +160,7 @@ def merge_topk_pallas(
     ids: jnp.ndarray,  # (B, M) int32 global ids, -1 sentinel padded
     k: int,
     tb: int = MERGE_TB,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused cross-shard top-k merge; semantics of ``ref.merge_topk_ref``.
 
@@ -161,23 +180,22 @@ def merge_topk_pallas(
         ids.astype(jnp.int32), ((0, pb), (0, pm)), constant_values=-1
     )
     mp = Dp.shape[1]
+    kp = _round_up(k, LANES)
+    out_block = pl.BlockSpec((tb, kp), lambda i: (i, 0))
     od, oi, osrc = pl.pallas_call(
         functools.partial(_merge_topk_kernel, k=k),
         out_shape=(
-            jax.ShapeDtypeStruct((Dp.shape[0], k), jnp.float32),
-            jax.ShapeDtypeStruct((Dp.shape[0], k), jnp.int32),
-            jax.ShapeDtypeStruct((Dp.shape[0], k), jnp.int32),
+            jax.ShapeDtypeStruct((Dp.shape[0], kp), jnp.float32),
+            jax.ShapeDtypeStruct((Dp.shape[0], kp), jnp.int32),
+            jax.ShapeDtypeStruct((Dp.shape[0], kp), jnp.int32),
         ),
         grid=(Dp.shape[0] // tb,),
         in_specs=[
             pl.BlockSpec((tb, mp), lambda i: (i, 0)),
             pl.BlockSpec((tb, mp), lambda i: (i, 0)),
         ],
-        out_specs=(
-            pl.BlockSpec((tb, k), lambda i: (i, 0)),
-            pl.BlockSpec((tb, k), lambda i: (i, 0)),
-            pl.BlockSpec((tb, k), lambda i: (i, 0)),
-        ),
+        out_specs=(out_block, out_block, out_block),
+        name="merge_topk",
         interpret=interpret,
     )(Dp, Ip)
-    return od[:B], oi[:B], osrc[:B]
+    return od[:B, :k], oi[:B, :k], osrc[:B, :k]

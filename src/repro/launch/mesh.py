@@ -35,11 +35,8 @@ def make_host_mesh(data: int = 1, model: int = 1):
 
 
 def make_shard_mesh(n_shards: int):
-    """1-D ("shard",) mesh over the first ``n_shards`` devices — the ANNS
-    index-sharding mesh (DESIGN.md §10). Unlike the production helpers
-    above this must run in-process on whatever jax the host has, so the
-    ``axis_types`` kwarg (absent on older jax) is applied only when the
-    enum exists."""
+    """1-D ("shard",) mesh over the first ``n_shards`` devices, one shard
+    per device — the ANNS index-sharding mesh (DESIGN.md §10)."""
     devs = jax.devices()
     if n_shards > len(devs):
         raise ValueError(
@@ -47,10 +44,9 @@ def make_shard_mesh(n_shards: int):
             "(set XLA_FLAGS=--xla_force_host_platform_device_count=N "
             "to simulate a mesh on CPU)"
         )
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = {"axis_types": (axis_type.Auto,)} if axis_type else {}
     return jax.make_mesh(
-        (n_shards,), ("shard",), devices=devs[:n_shards], **kw
+        (n_shards,), ("shard",), devices=devs[:n_shards],
+        axis_types=(jax.sharding.AxisType.Auto,),
     )
 
 

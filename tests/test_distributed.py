@@ -103,9 +103,8 @@ out["ranges_cover"] = bool(
 # legacy flat-scan substrate smoke (dryrun path)
 from repro.core.distributed import build_sharded_index, distributed_brute_force
 from repro.core.hnsw import exact_search
-_ax = getattr(jax.sharding, "AxisType", None)
 mesh2 = jax.make_mesh((4, 2), ("data", "model"),
-                      **({"axis_types": (_ax.Auto,) * 2} if _ax else {}))
+                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
 idx = build_sharded_index(X, 4, M=8, ef_construction=60)
 with mesh2:
     fd, fi = distributed_brute_force(mesh2, k=k)(jnp.asarray(Q), idx)

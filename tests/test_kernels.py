@@ -34,7 +34,8 @@ def test_distance_shapes(metric, B, N, d, tq, tn, td):
     Q = RNG.standard_normal((B, d)).astype(np.float32)
     X = RNG.standard_normal((N, d)).astype(np.float32)
     out = distance_matrix_pallas(
-        jnp.asarray(Q), jnp.asarray(X), metric=metric, tq=tq, tn=tn, td=td
+        jnp.asarray(Q), jnp.asarray(X), metric=metric, tq=tq, tn=tn, td=td,
+        interpret=True,
     )
     want = ref.distance_matrix_ref(jnp.asarray(Q), jnp.asarray(X), metric)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -45,7 +46,7 @@ def test_distance_shapes(metric, B, N, d, tq, tn, td):
 def test_distance_dtypes(dtype):
     Q = jnp.asarray(RNG.standard_normal((16, 32)), dtype)
     X = jnp.asarray(RNG.standard_normal((48, 32)), dtype)
-    out = distance_matrix_pallas(Q, X, tq=8, tn=16, td=32)
+    out = distance_matrix_pallas(Q, X, tq=8, tn=16, td=32, interpret=True)
     want = ref.distance_matrix_ref(Q, X, "l2")
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -62,7 +63,7 @@ def test_distance_property(b, n, d, seed):
     Q = r.standard_normal((b, d)).astype(np.float32)
     X = r.standard_normal((n, d)).astype(np.float32)
     out = distance_matrix_pallas(jnp.asarray(Q), jnp.asarray(X),
-                                 tq=8, tn=8, td=8)
+                                 tq=8, tn=8, td=8, interpret=True)
     want = ref.distance_matrix_ref(jnp.asarray(Q), jnp.asarray(X), "l2")
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -83,7 +84,7 @@ def test_distance_property(b, n, d, seed):
 )
 def test_topk_shapes(B, N, k, tb, tn):
     D = RNG.standard_normal((B, N)).astype(np.float32)
-    dd, ii = topk_pallas(jnp.asarray(D), k=k, tb=tb, tn=tn)
+    dd, ii = topk_pallas(jnp.asarray(D), k=k, tb=tb, tn=tn, interpret=True)
     rd, ri = ref.topk_ref(jnp.asarray(D), k)
     np.testing.assert_allclose(np.asarray(dd), np.asarray(rd), rtol=1e-6)
     # ids may differ on exact ties; verify via gathered values instead
@@ -94,7 +95,7 @@ def test_topk_shapes(B, N, k, tb, tn):
 def test_topk_with_infs():
     D = np.full((4, 64), np.inf, np.float32)
     D[0, 5] = 1.0
-    dd, ii = topk_pallas(jnp.asarray(D), k=3, tb=4, tn=32)
+    dd, ii = topk_pallas(jnp.asarray(D), k=3, tb=4, tn=32, interpret=True)
     assert int(ii[0, 0]) == 5
     assert float(dd[0, 0]) == 1.0
 
@@ -109,7 +110,8 @@ def test_gather_distance_shapes(metric, N, d, B):
     ids = RNG.integers(-1, N, size=B).astype(np.int32)
     q = RNG.standard_normal(d).astype(np.float32)
     out = gather_distance_pallas(
-        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(q), metric=metric
+        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(q), metric=metric,
+        interpret=True,
     )
     want = ref.gather_distance_ref(
         jnp.asarray(table), jnp.asarray(ids), jnp.asarray(q), metric
@@ -130,7 +132,7 @@ def test_embedding_bag_shapes(combiner, V, d, B, S):
     table = RNG.standard_normal((V, d)).astype(np.float32)
     idx = RNG.integers(-1, V, size=(B, S)).astype(np.int32)
     out = embedding_bag_pallas(jnp.asarray(table), jnp.asarray(idx),
-                               combiner=combiner)
+                               combiner=combiner, interpret=True)
     want = ref.embedding_bag_ref(jnp.asarray(table), jnp.asarray(idx),
                                  None, combiner)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -140,7 +142,8 @@ def test_embedding_bag_shapes(combiner, V, d, B, S):
 def test_embedding_bag_all_padding_row():
     table = RNG.standard_normal((10, 4)).astype(np.float32)
     idx = np.array([[-1, -1], [0, 1]], np.int32)
-    out = embedding_bag_pallas(jnp.asarray(table), jnp.asarray(idx))
+    out = embedding_bag_pallas(jnp.asarray(table), jnp.asarray(idx),
+                               interpret=True)
     np.testing.assert_allclose(np.asarray(out[0]), 0.0)
 
 

@@ -305,6 +305,20 @@ def test_allocator_uncontended_grants_optima_plus_surplus():
     assert alloc.total_alloc_bytes <= alloc.budget_bytes
 
 
+def test_allocator_uncontended_surplus_snaps_down_within_budget():
+    """A surplus that ends mid-grain is snapped down, never up past the
+    byte budget."""
+    bpi = bytes_per_vector(DIM, "float32")
+    budget = 1000 * bpi  # 62.5 grains of 16 items
+    alloc = allocate_memory_bytes(
+        [_fake_demand("a", 8192, 1.0)], budget, reserve_frac=0.0,
+        shape_grain=16,
+    )
+    assert not alloc.contended
+    assert alloc.allocations["a"].c_items == 62 * 16
+    assert alloc.total_alloc_bytes <= budget
+
+
 def test_allocator_contended_respects_budget_and_floors():
     bpi = bytes_per_vector(DIM, "float32")
     demands = [_fake_demand("a", 512, 3.0, hard=5000.0),
