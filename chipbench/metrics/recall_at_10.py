@@ -1,0 +1,6 @@
+"""Mean recall@10 of every query answered in the window, against the
+exact top-10 of the reference."""
+
+
+def read(run):
+    return run.recall
