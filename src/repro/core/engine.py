@@ -70,12 +70,15 @@ from repro.core.graph import HNSWGraph, random_levels
 from repro.core.hnsw import build_hnsw, insert_hnsw
 from repro.core.index import Index
 from repro.core.metadata import Filter, MetadataStore
+from repro.core.spans import span, to_host
 from repro.core.storage import StorageBackend
 from repro.core.store import (
+    EVICT_LRU,
     CacheState,
     ExternalStore,
     TieredStore,
     cache_lookup,
+    cache_touch,
 )
 
 
@@ -901,6 +904,9 @@ class WebANNSEngine:
             "items_fetched": s.items_fetched,
             "items_used": s.items_used,
             "modeled_time": s.modeled_time,
+            "tier2_hits": s.tier2_hits,
+            "tier2_misses": s.tier2_misses,
+            "host_syncs": s.host_syncs,
         }
 
     def warm_cache(self, ids: Optional[np.ndarray] = None) -> None:
@@ -946,12 +952,15 @@ class WebANNSEngine:
         valid = ids >= 0
         if not valid.any():
             return ids[:k], dists[:k]
-        fetched = self.external.fetch(ids[valid])
-        self.external.mark_used_ids(ids[valid])
-        exact = np.full(ids.shape, np.inf, np.float32)
-        exact[valid] = _np_point_distance(fetched, q, self.config.metric)
-        order = np.argsort(exact, kind="stable")
-        return ids[order][:k], exact[order][:k]
+        with span("rerank"):
+            fetched = self.external.fetch(ids[valid])
+            self.external.mark_used_ids(ids[valid])
+            exact = np.full(ids.shape, np.inf, np.float32)
+            exact[valid] = _np_point_distance(
+                fetched, q, self.config.metric
+            )
+            order = np.argsort(exact, kind="stable")
+            return ids[order][:k], exact[order][:k]
 
     def _rerank_exact_batch(
         self, Q: np.ndarray, ids: np.ndarray, dists: np.ndarray, k: int
@@ -965,25 +974,26 @@ class WebANNSEngine:
         valid = ids >= 0
         if not valid.any():
             return ids[:, :k], dists[:, :k]
-        union = np.unique(ids[valid])  # sorted — searchsorted below
-        fetched = self.external.fetch(union)
-        self.external.mark_used_ids(union)
-        exact = np.full((B, m), np.inf, np.float32)
-        # rows/qidx are in ids[valid]'s row-major order, so per-row
-        # distances scatter back through one flat buffer
-        rows = fetched[np.searchsorted(union, ids[valid])]
-        qidx = np.broadcast_to(np.arange(B)[:, None], (B, m))[valid]
-        flat = np.empty(rows.shape[0], np.float32)
-        for b in range(B):
-            sel = qidx == b
-            if sel.any():
-                flat[sel] = _np_point_distance(
-                    rows[sel], Q[b], self.config.metric
-                )
-        exact[valid] = flat
-        order = np.argsort(exact, axis=1, kind="stable")
-        return (np.take_along_axis(ids, order, 1)[:, :k],
-                np.take_along_axis(exact, order, 1)[:, :k])
+        with span("rerank"):
+            union = np.unique(ids[valid])  # sorted — searchsorted below
+            fetched = self.external.fetch(union)
+            self.external.mark_used_ids(union)
+            exact = np.full((B, m), np.inf, np.float32)
+            # rows/qidx are in ids[valid]'s row-major order, so per-row
+            # distances scatter back through one flat buffer
+            rows = fetched[np.searchsorted(union, ids[valid])]
+            qidx = np.broadcast_to(np.arange(B)[:, None], (B, m))[valid]
+            flat = np.empty(rows.shape[0], np.float32)
+            for b in range(B):
+                sel = qidx == b
+                if sel.any():
+                    flat[sel] = _np_point_distance(
+                        rows[sel], Q[b], self.config.metric
+                    )
+            exact[valid] = flat
+            order = np.argsort(exact, axis=1, kind="stable")
+            return (np.take_along_axis(ids, order, 1)[:, :k],
+                    np.take_along_axis(exact, order, 1)[:, :k])
 
     # ------------------------------------------------------------- query
 
@@ -994,52 +1004,57 @@ class WebANNSEngine:
     ) -> S.SearchState:
         """Run one layer with phased lazy loading (or eager fetches)."""
         cfg = self.config
+        acc = self.external.stats
         miss_cap = ef + self.graph.max_degree + 1
-        dummy = jnp.zeros((miss_cap,), jnp.int32)
-        entry_np = np.full(max(len(entry_ids), 1), -1, np.int32)
-        entry_np[: len(entry_ids)] = entry_ids
-        state = _seed_cached(
-            q, jnp.asarray(entry_np), self.store.cache, ef, dummy,
-            cfg.metric, self._tombs_device(),
-            self._noban_device() if banned is None else banned,
-        )
+        with span("seed"):
+            dummy = jnp.zeros((miss_cap,), jnp.int32)
+            entry_np = np.full(max(len(entry_ids), 1), -1, np.int32)
+            entry_np[: len(entry_ids)] = entry_ids
+            state = _seed_cached(
+                q, jnp.asarray(entry_np), self.store.cache, ef, dummy,
+                cfg.metric, self._tombs_device(),
+                self._noban_device() if banned is None else banned,
+            )
         # eager mode (webanns-base): trigger=1 → flush L after every miss
         trigger = 1 if eager else ef
-        from repro.core.store import EVICT_LRU, cache_touch
 
         for _ in range(cfg.max_phases):
             t0 = time.perf_counter()
-            state = _phase_cached(
-                q, self.neighbors[layer], state, self.store.cache,
-                cfg.metric, trigger,
-            )
-            mc = int(state.miss_count)
-            if self.store.eviction == EVICT_LRU:
-                # phase-boundary touch: the beam approximates the
-                # recently-used set (in-phase hits can't touch in-graph)
-                self.store.cache = cache_touch(
-                    self.store.cache, state.beam.ids
+            with span("beam_phase"):
+                state = _phase_cached(
+                    q, self.neighbors[layer], state, self.store.cache,
+                    cfg.metric, trigger,
                 )
+                mc = int(to_host(state.miss_count, acc))
+                if self.store.eviction == EVICT_LRU:
+                    # phase-boundary touch: the beam approximates the
+                    # recently-used set (in-phase hits can't touch in-graph)
+                    self.store.cache = cache_touch(
+                        self.store.cache, state.beam.ids
+                    )
             stats.t_in_mem += time.perf_counter() - t0
+            acc.tier2_misses += mc
             if mc == 0:
                 break
             # ONE tier-3 access for the whole lazy list (Alg. 1 line 24)
-            miss_ids = np.asarray(state.miss_ids[:mc])
-            db0 = self.external.stats.n_db
-            vecs = self.store.gather(miss_ids)
-            stats.n_db += self.external.stats.n_db - db0
+            with span("tier2_gather"):
+                miss_ids = to_host(state.miss_ids[:mc], acc)
+                db0 = acc.n_db
+                vecs = self.store.gather(miss_ids)
+            stats.n_db += acc.n_db - db0
             stats.items_fetched += len(miss_ids)
-            # pad host-side (fixed shapes → zero eager-op compiles)
-            padded_ids = np.full((miss_cap,), -1, np.int32)
-            padded_ids[:mc] = miss_ids
-            padded_vecs = np.zeros((miss_cap, self.dim), np.float32)
-            padded_vecs[:mc] = vecs
-            t0 = time.perf_counter()
-            state = _load_cached(
-                q, state, jnp.asarray(padded_ids), jnp.asarray(padded_vecs),
-                cfg.metric,
-            )
-            stats.t_in_mem += time.perf_counter() - t0
+            with span("load_phase"):
+                # pad host-side (fixed shapes → zero eager-op compiles)
+                padded_ids = np.full((miss_cap,), -1, np.int32)
+                padded_ids[:mc] = miss_ids
+                padded_vecs = np.zeros((miss_cap, self.dim), np.float32)
+                padded_vecs[:mc] = vecs
+                t0 = time.perf_counter()
+                state = _load_cached(
+                    q, state, jnp.asarray(padded_ids),
+                    jnp.asarray(padded_vecs), cfg.metric,
+                )
+                stats.t_in_mem += time.perf_counter() - t0
         return state
 
     def _batched_lazy_layer(
@@ -1055,54 +1070,60 @@ class WebANNSEngine:
         the whole batch; the bulk load is scattered back per query.
         """
         cfg = self.config
+        acc = self.external.stats
         miss_cap = ef + self.graph.max_degree + 1
         trigger = 1 if eager else ef
-        from repro.core.store import EVICT_LRU, cache_touch
 
         t0 = time.perf_counter()
-        if banned is None:
-            banned = jnp.broadcast_to(
-                self._noban_device(), (Q.shape[0], self.n)
+        with span("seed"):
+            if banned is None:
+                banned = jnp.broadcast_to(
+                    self._noban_device(), (Q.shape[0], self.n)
+                )
+            states = _batch_seed_cached(
+                Q, jnp.asarray(entry_ids), self.store.cache, ef, miss_cap,
+                cfg.metric, self._tombs_device(), banned,
             )
-        states = _batch_seed_cached(
-            Q, jnp.asarray(entry_ids), self.store.cache, ef, miss_cap,
-            cfg.metric, self._tombs_device(), banned,
-        )
         bstats.t_in_mem += time.perf_counter() - t0
         for _ in range(cfg.max_phases):
             t0 = time.perf_counter()
-            states = _batch_phase_cached(
-                Q, self.neighbors[layer], states, self.store.cache,
-                cfg.metric, trigger,
-            )
-            mc = np.asarray(states.miss_count)
-            if self.store.eviction == EVICT_LRU:
-                self.store.cache = cache_touch(
-                    self.store.cache, states.beam.ids.reshape(-1)
+            with span("beam_phase"):
+                states = _batch_phase_cached(
+                    Q, self.neighbors[layer], states, self.store.cache,
+                    cfg.metric, trigger,
                 )
+                mc = to_host(states.miss_count, acc)
+                if self.store.eviction == EVICT_LRU:
+                    self.store.cache = cache_touch(
+                        self.store.cache, states.beam.ids.reshape(-1)
+                    )
             bstats.t_in_mem += time.perf_counter() - t0
-            if int(mc.sum()) == 0:
+            n_miss = int(mc.sum())
+            acc.tier2_misses += n_miss
+            if n_miss == 0:
                 break
-            miss_np = np.asarray(states.miss_ids)
             # ONE tier-3 access for the union of all B miss lists
-            db0 = self.external.stats.n_db
-            fetched0 = self.external.stats.items_fetched
-            vecs = self.store.gather_batch(miss_np)
-            bstats.n_db += self.external.stats.n_db - db0
-            bstats.items_fetched += (
-                self.external.stats.items_fetched - fetched0
-            )
+            with span("tier2_gather"):
+                miss_np = to_host(states.miss_ids, acc)
+                db0 = acc.n_db
+                fetched0 = acc.items_fetched
+                vecs = self.store.gather_batch(miss_np)
+            bstats.n_db += acc.n_db - db0
+            bstats.items_fetched += acc.items_fetched - fetched0
             bstats.n_phases += 1
             # per-query demand: which queries needed this shared access
             for b in np.nonzero(mc > 0)[0]:
                 per_stats[b].n_db += 1
                 per_stats[b].items_fetched += int(mc[b])
             t0 = time.perf_counter()
-            # states.miss_ids is already device-resident and fixed-shape;
-            # only the fetched vectors need the host→device hop
-            states = _batch_load_cached(
-                Q, states, states.miss_ids, jnp.asarray(vecs), cfg.metric
-            )
+            with span("load_phase"):
+                # states.miss_ids is already device-resident and
+                # fixed-shape; only the fetched vectors need the
+                # host→device hop
+                states = _batch_load_cached(
+                    Q, states, states.miss_ids, jnp.asarray(vecs),
+                    cfg.metric,
+                )
             bstats.t_in_mem += time.perf_counter() - t0
         return states
 
@@ -1110,6 +1131,9 @@ class WebANNSEngine:
         self, q: np.ndarray, k: int, ef: int,
         banned: Optional[jnp.ndarray] = None,
     ) -> Tuple[np.ndarray, np.ndarray, QueryStats]:
+        """Fused single-query driver: the whole lazy query is one device
+        program, so the host phase loop's counters (``tier2_hits``,
+        ``tier2_misses``, ``host_syncs``) do not move."""
         cfg = self.config
         stats = QueryStats()
         if not hasattr(self, "_table_dev"):
@@ -1210,47 +1234,51 @@ class WebANNSEngine:
         if cfg.fused and cfg.mode == "webanns":
             return self._query_fused(q, k, ef, banned=banned)
         eager = cfg.mode == "webanns-base"
+        acc = self.external.stats
+        misses0 = acc.tier2_misses
         stats = QueryStats()
         qj = jnp.asarray(q, jnp.float32)
-        t_db0 = self.external.stats.modeled_time
+        t_db0 = acc.modeled_time
         entry = np.array([self.graph.entry_point], np.int32)
         # upper layers: beam of ef_upper (greedy for 1), lazily loaded too;
         # the deny mask is irrelevant here (descent only routes)
         for lc in range(self.graph.max_level, 0, -1):
             st = self._lazy_layer(qj, lc, entry, cfg.ef_upper, stats, eager)
-            best = np.asarray(st.beam.ids[: cfg.ef_upper])
-            entry = best[best >= 0][:1] if (best >= 0).any() else entry
-            stats.n_hops += int(st.n_hops)
-            stats.n_dist += int(st.n_dist)
+            with span("descend"):
+                best = to_host(st.beam.ids[: cfg.ef_upper], acc)
+                entry = best[best >= 0][:1] if (best >= 0).any() else entry
+                stats.n_hops += int(to_host(st.n_hops, acc))
+                stats.n_dist += int(to_host(st.n_dist, acc))
         st = self._lazy_layer(
             qj, 0, entry, max(ef, k), stats, eager, banned=banned
         )
-        stats.n_hops += int(st.n_hops)
-        stats.n_dist += int(st.n_dist)
-        stats.n_visited = stats.n_dist  # every visited id gets a distance
-        if self._rerank_active():
-            pool = min(st.beam.ef, quant.rerank_pool(k, cfg.rerank_alpha))
-            if filt is not None:
-                # allowed-only pool: a banned id must never reach the
-                # rerank fetch, let alone the returned top-k
-                p_dists, p_ids = _finalize_cached(st, pool)
+        with span("finalize"):
+            stats.n_hops += int(to_host(st.n_hops, acc))
+            stats.n_dist += int(to_host(st.n_dist, acc))
+            stats.n_visited = stats.n_dist  # every visited id gets a distance
+            if self._rerank_active():
+                pool = min(st.beam.ef, quant.rerank_pool(k, cfg.rerank_alpha))
+                if filt is not None:
+                    # allowed-only pool: a banned id must never reach the
+                    # rerank fetch, let alone the returned top-k
+                    p_dists, p_ids = _finalize_cached(st, pool)
+                else:
+                    p_ids = st.beam.ids[:pool]
+                    p_dists = st.beam.dists[:pool]
+                db0, f0 = acc.n_db, acc.items_fetched
+                ids, dists = self._rerank_exact(
+                    q, to_host(p_ids, acc), to_host(p_dists, acc), k,
+                )
+                stats.n_db += acc.n_db - db0
+                stats.items_fetched += acc.items_fetched - f0
+            elif filt is not None:
+                f_dists, f_ids = _finalize_cached(st, k)
+                ids, dists = to_host(f_ids, acc), to_host(f_dists, acc)
             else:
-                p_ids = st.beam.ids[:pool]
-                p_dists = st.beam.dists[:pool]
-            db0, f0 = self.external.stats.n_db, \
-                self.external.stats.items_fetched
-            ids, dists = self._rerank_exact(
-                q, np.asarray(p_ids), np.asarray(p_dists), k,
-            )
-            stats.n_db += self.external.stats.n_db - db0
-            stats.items_fetched += self.external.stats.items_fetched - f0
-        elif filt is not None:
-            f_dists, f_ids = _finalize_cached(st, k)
-            ids, dists = np.asarray(f_ids), np.asarray(f_dists)
-        else:
-            ids = np.asarray(st.beam.ids[:k])
-            dists = np.asarray(st.beam.dists[:k])
-        stats.t_db = self.external.stats.modeled_time - t_db0
+                ids = to_host(st.beam.ids[:k], acc)
+                dists = to_host(st.beam.dists[:k], acc)
+        acc.tier2_hits += stats.n_dist - (acc.tier2_misses - misses0)
+        stats.t_db = acc.modeled_time - t_db0
         return ids, dists, stats
 
     def _normalize_filters(
@@ -1327,6 +1355,8 @@ class WebANNSEngine:
         spells out the protocol). Traversal performs
         ZERO tier-3 accesses (each shard's slab is 100% resident, the
         fused-path memory model); only the exact-rerank pass fetches.
+        With no host phase loop, ``tier2_hits``, ``tier2_misses`` and
+        ``host_syncs`` do not move.
         """
         cfg = self.config
         B = len(Q)
@@ -1505,6 +1535,8 @@ class WebANNSEngine:
                 f"batch_mode must be 'batched' or 'loop', got {batch_mode!r}"
             )
         eager = cfg.mode == "webanns-base"
+        acc = self.external.stats
+        misses0 = acc.tier2_misses
         bstats = BatchStats(batch_size=B)
         per_stats = [QueryStats() for _ in range(B)]
         Qj = jnp.asarray(Q)
@@ -1519,56 +1551,55 @@ class WebANNSEngine:
                 if row is not None:
                     banned_np[b] = row
             banned_mat = jnp.asarray(banned_np)
-        t_db0 = self.external.stats.modeled_time
+        t_db0 = acc.modeled_time
         entry = np.full((B, 1), self.graph.entry_point, np.int32)
         for lc in range(self.graph.max_level, 0, -1):
             st = self._batched_lazy_layer(
                 Qj, lc, entry, cfg.ef_upper, per_stats, bstats, eager
             )
-            best = np.asarray(st.beam.ids[:, : cfg.ef_upper])
-            hops = np.asarray(st.n_hops)
-            ndist = np.asarray(st.n_dist)
-            for b in range(B):
-                row = best[b][best[b] >= 0]
-                if len(row):
-                    entry[b, 0] = row[0]
-                per_stats[b].n_hops += int(hops[b])
-                per_stats[b].n_dist += int(ndist[b])
+            with span("descend"):
+                best = to_host(st.beam.ids[:, : cfg.ef_upper], acc)
+                hops = to_host(st.n_hops, acc)
+                ndist = to_host(st.n_dist, acc)
+                for b in range(B):
+                    row = best[b][best[b] >= 0]
+                    if len(row):
+                        entry[b, 0] = row[0]
+                    per_stats[b].n_hops += int(hops[b])
+                    per_stats[b].n_dist += int(ndist[b])
         st = self._batched_lazy_layer(
             Qj, 0, entry, max(ef, k), per_stats, bstats, eager,
             banned=banned_mat,
         )
-        hops = np.asarray(st.n_hops)
-        ndist = np.asarray(st.n_dist)
-        if self._rerank_active():
-            # ONE shared tier-3 access reranks the whole batch (§5/§7)
-            pool = min(int(st.beam.ids.shape[1]),
-                       quant.rerank_pool(k, cfg.rerank_alpha))
-            if banned_mat is not None:
-                # per-query allowed-only pools: banned ids never reach
-                # the rerank fetch (route-but-don't-return, §9)
-                p_dists, p_ids = _finalize_cached(st, pool)  # lint: disable=R003 -- pool ≤ k·α with the beam width grain-snapped in _boost_ef; bounded trace set
+        with span("finalize"):
+            hops = to_host(st.n_hops, acc)
+            ndist = to_host(st.n_dist, acc)
+            if self._rerank_active():
+                # ONE shared tier-3 access reranks the whole batch (§5/§7)
+                pool = min(int(st.beam.ids.shape[1]),
+                           quant.rerank_pool(k, cfg.rerank_alpha))
+                if banned_mat is not None:
+                    # per-query allowed-only pools: banned ids never reach
+                    # the rerank fetch (route-but-don't-return, §9)
+                    p_dists, p_ids = _finalize_cached(st, pool)  # lint: disable=R003 -- pool ≤ k·α with the beam width grain-snapped in _boost_ef; bounded trace set
+                else:
+                    p_ids = st.beam.ids[:, :pool]
+                    p_dists = st.beam.dists[:, :pool]
+                db0, f0 = acc.n_db, acc.items_fetched
+                ids, dists = self._rerank_exact_batch(
+                    Q, to_host(p_ids, acc), to_host(p_dists, acc), k,
+                )
+                bstats.n_db += acc.n_db - db0
+                bstats.items_fetched += acc.items_fetched - f0
+                for b in range(B):  # every query demanded the shared rerank
+                    per_stats[b].n_db += 1
+            elif banned_mat is not None:
+                f_dists, f_ids = _finalize_cached(st, k)
+                ids, dists = to_host(f_ids, acc), to_host(f_dists, acc)
             else:
-                p_ids = st.beam.ids[:, :pool]
-                p_dists = st.beam.dists[:, :pool]
-            db0 = self.external.stats.n_db
-            f0 = self.external.stats.items_fetched
-            ids, dists = self._rerank_exact_batch(
-                Q, np.asarray(p_ids), np.asarray(p_dists), k,
-            )
-            bstats.n_db += self.external.stats.n_db - db0
-            bstats.items_fetched += (
-                self.external.stats.items_fetched - f0
-            )
-            for b in range(B):  # every query demanded the shared rerank
-                per_stats[b].n_db += 1
-        elif banned_mat is not None:
-            f_dists, f_ids = _finalize_cached(st, k)
-            ids, dists = np.asarray(f_ids), np.asarray(f_dists)
-        else:
-            ids = np.asarray(st.beam.ids[:, :k])
-            dists = np.asarray(st.beam.dists[:, :k])
-        bstats.t_db = self.external.stats.modeled_time - t_db0
+                ids = to_host(st.beam.ids[:, :k], acc)
+                dists = to_host(st.beam.dists[:, :k], acc)
+        bstats.t_db = acc.modeled_time - t_db0
         for b in range(B):
             per_stats[b].n_hops += int(hops[b])
             per_stats[b].n_dist += int(ndist[b])
@@ -1576,6 +1607,8 @@ class WebANNSEngine:
             # amortized per-query share of the batch's wall/model time
             per_stats[b].t_in_mem = bstats.t_in_mem / B
             per_stats[b].t_db = bstats.t_db / B
+        acc.tier2_hits += sum(s.n_dist for s in per_stats) \
+            - (acc.tier2_misses - misses0)
         self.last_batch_stats = bstats
         return ids, dists, per_stats
 
@@ -1589,6 +1622,10 @@ class WebANNSEngine:
         also carries the whole-batch accounting in
         ``SearchResult.batch_stats``.
         """
+        with span("search"):
+            return self._search(request)
+
+    def _search(self, request: SearchRequest) -> SearchResult:
         q = np.asarray(request.query, dtype=np.float32)
         if q.ndim == 1:
             filt = request.filter

@@ -1,0 +1,37 @@
+"""Host spans of the search drivers and the tiered store (DESIGN.md §5).
+
+Each span is a ``jax.profiler.TraceAnnotation``: it lands in the same
+trace as the device's programs when a profiler runs, and costs about
+half a microsecond when none does, so it needs no switch. A span's
+parent is the span that contains it on the calling thread; every span
+of one request lies inside that request's ``search`` span.
+"""
+
+from __future__ import annotations
+
+import jax
+import numpy as np
+
+# every span the program writes, in the order a request meets them
+SPAN_NAMES = (
+    "search",  # WebANNSEngine.search: the whole call, every driver
+    "seed",  # layer entry: seed-program dispatch
+    "beam_phase",  # phase dispatch and the wait for its miss count
+    "tier2_gather",  # miss-id read through TieredStore.gather(_batch)
+    "tier3_fetch",  # ExternalStore.fetch: the backend read
+    "load_phase",  # host->device copy of the rows, load-program dispatch
+    "descend",  # between-layer reads and the entry update
+    "finalize",  # the final top-k read
+    "rerank",  # exact rerank of a quantized search (one tier-3 access)
+)
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    return jax.profiler.TraceAnnotation(name)
+
+
+def to_host(x, stats) -> np.ndarray:
+    """``np.asarray(x)``, a blocking device->host read, counted in
+    ``stats.host_syncs`` (an :class:`~repro.core.store.AccessStats`)."""
+    stats.host_syncs += 1
+    return np.asarray(x)

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pq, quant
+from repro.core.spans import span, to_host
 from repro.core.storage import (  # noqa: F401  (re-exported, DESIGN.md §6)
     DeltaBackend,
     InMemoryBackend,
@@ -352,13 +353,27 @@ def cache_insert(
 
 @dataclasses.dataclass
 class AccessStats:
-    """Counters behind Eq. 1 (redundancy) and Eq. 2 (latency model)."""
+    """Counters behind Eq. 1 (redundancy) and Eq. 2 (latency model),
+    and the host phase loop's tier-2 and sync counters.
+
+    The three last counters move only in the host-driven lazy drivers
+    (single-query and batched); the fused and sharded drivers run their
+    phases on the device and leave them unchanged. ``tier2_misses``
+    counts the ids the phase programs pushed to the lazy list;
+    ``tier2_hits`` the neighbour lookups tier 2 served inside beam
+    phases (distance evaluations less misses; the hits of a layer's
+    entry probe are not counted). ``host_syncs`` counts the blocking
+    device->host reads of those drivers and of ``TieredStore.gather``.
+    """
 
     n_db: int = 0  # number of external accesses (transactions)
     items_fetched: int = 0  # total items pulled from tier 3
     items_used: int = 0  # items that were actually needed (#hit in Eq. 1)
     modeled_time: float = 0.0  # sum of modeled t_db per access
     wall_time: float = 0.0  # measured host time in fetch calls
+    tier2_hits: int = 0  # beam-phase lookups served by tier 2
+    tier2_misses: int = 0  # ids pushed to the lazy list
+    host_syncs: int = 0  # blocking device->host reads
 
     def redundancy(self) -> float:
         """Eq. 1: R = 1 - hits / (n_db * prefetch_size)."""
@@ -372,6 +387,9 @@ class AccessStats:
         self.items_used = 0
         self.modeled_time = 0.0
         self.wall_time = 0.0
+        self.tier2_hits = 0
+        self.tier2_misses = 0
+        self.host_syncs = 0
 
 
 class ExternalStore:
@@ -468,17 +486,18 @@ class ExternalStore:
 
     def fetch(self, ids: np.ndarray) -> np.ndarray:
         """ONE external access (one 'transaction') for a batch of ids."""
-        t0 = time.perf_counter()
-        ids = np.asarray(ids)
-        ids = ids[ids >= 0]
-        out = self.backend.fetch(ids)
-        cost = self.access_cost(len(ids))
-        self.stats.n_db += 1
-        self.stats.items_fetched += len(ids)
-        self.stats.modeled_time += cost
-        self.stats.wall_time += time.perf_counter() - t0
-        self._pending.update(int(i) for i in ids)
-        return out
+        with span("tier3_fetch"):
+            t0 = time.perf_counter()
+            ids = np.asarray(ids)
+            ids = ids[ids >= 0]
+            out = self.backend.fetch(ids)
+            cost = self.access_cost(len(ids))
+            self.stats.n_db += 1
+            self.stats.items_fetched += len(ids)
+            self.stats.modeled_time += cost
+            self.stats.wall_time += time.perf_counter() - t0
+            self._pending.update(int(i) for i in ids)
+            return out
 
     def fetch_sequential(self, ids: np.ndarray) -> np.ndarray:
         """n separate accesses for n items (paper Fig. 3b's slow path)."""
@@ -528,8 +547,6 @@ class TieredStore:
             external.n_items, capacity, external.dim, self.precision,
             codebook=codebook,
         )
-        self.hits = 0
-        self.misses = 0
 
     @property
     def capacity(self) -> int:
@@ -547,8 +564,6 @@ class TieredStore:
             self.external.n_items, capacity, self.external.dim,
             self.precision, codebook=self.codebook,
         )
-        self.hits = 0
-        self.misses = 0
 
     def lookup(self, ids: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
         return cache_lookup(self.cache, ids)
@@ -589,12 +604,10 @@ class TieredStore:
         k = len(ids)
         padded = self._pad_pow2(ids)
         present, vecs = cache_lookup(self.cache, jnp.asarray(padded))
-        present = np.asarray(present)[:k]
-        vecs = np.array(vecs)[:k]  # writable host copy
-        n_miss = int((~present).sum())
-        self.hits += int(present.sum())
-        self.misses += n_miss
-        if n_miss:
+        stats = self.external.stats
+        present = to_host(present, stats)[:k]
+        vecs = np.array(to_host(vecs, stats)[:k])  # writable host copy
+        if not present.all():
             miss_ids = ids[~present]
             fetched = self.external.fetch(miss_ids)
             miss_padded = self._pad_pow2(miss_ids)
