@@ -1067,7 +1067,9 @@ class WebANNSEngine:
         All B queries advance one in-memory phase together (vmapped
         against the same tier-2 snapshot); their miss lists are unioned,
         deduplicated, and satisfied by ONE tier-3 access per phase for
-        the whole batch; the bulk load is scattered back per query.
+        the whole batch; the bulk load is scattered back per query on
+        the device (``TieredStore.fill_batch``: every listed id missed
+        that same snapshot, so the store looks none of them up again).
         """
         cfg = self.config
         acc = self.external.stats
@@ -1107,7 +1109,7 @@ class WebANNSEngine:
                 miss_np = to_host(states.miss_ids, acc)
                 db0 = acc.n_db
                 fetched0 = acc.items_fetched
-                vecs = self.store.gather_batch(miss_np)
+                vecs = self.store.fill_batch(miss_np)
             bstats.n_db += acc.n_db - db0
             bstats.items_fetched += acc.items_fetched - fetched0
             bstats.n_phases += 1
@@ -1117,12 +1119,9 @@ class WebANNSEngine:
                 per_stats[b].items_fetched += int(mc[b])
             t0 = time.perf_counter()
             with span("load_phase"):
-                # states.miss_ids is already device-resident and
-                # fixed-shape; only the fetched vectors need the
-                # host→device hop
+                # the miss ids and the filled rows are both device-resident
                 states = _batch_load_cached(
-                    Q, states, states.miss_ids, jnp.asarray(vecs),
-                    cfg.metric,
+                    Q, states, states.miss_ids, vecs, cfg.metric,
                 )
             bstats.t_in_mem += time.perf_counter() - t0
         return states

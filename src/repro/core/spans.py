@@ -17,9 +17,9 @@ SPAN_NAMES = (
     "search",  # WebANNSEngine.search: the whole call, every driver
     "seed",  # layer entry: seed-program dispatch
     "beam_phase",  # phase dispatch and the wait for its miss count
-    "tier2_gather",  # miss-id read through TieredStore.gather(_batch)
+    "tier2_gather",  # miss-id read, TieredStore.gather / fill_batch
     "tier3_fetch",  # ExternalStore.fetch: the backend read
-    "load_phase",  # host->device copy of the rows, load-program dispatch
+    "load_phase",  # load-program dispatch (single-query: the rows' copy)
     "descend",  # between-layer reads and the entry update
     "finalize",  # the final top-k read
     "rerank",  # exact rerank of a quantized search (one tier-3 access)

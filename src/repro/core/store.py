@@ -348,6 +348,24 @@ def cache_insert(
     )
 
 
+@functools.partial(jax.jit, static_argnames=("policy",))
+def _fill_union(
+    cache: CacheState,
+    ids: jnp.ndarray,  # (u,) int32 sorted union, -1 padded
+    rows: jnp.ndarray,  # (u, d) float32 tier-3 rows, zero padded
+    inv: jnp.ndarray,  # (B, k) int32 row of each miss in ``ids``, -1 padded
+    policy: int,
+) -> Tuple[CacheState, jnp.ndarray]:
+    """Insert a union of known misses, touch it under LRU, and scatter
+    its rows back to the (B, k) miss lists: :meth:`TieredStore.fill_batch`'s
+    one device program."""
+    cache = cache_insert(cache, ids, rows, policy=policy)
+    if policy == EVICT_LRU:
+        cache = cache_touch(cache, ids)
+    out = jnp.where((inv >= 0)[..., None], rows[jnp.maximum(inv, 0)], 0.0)
+    return cache, out
+
+
 # --------------------------------------------------------------- tier 3
 
 
@@ -363,7 +381,8 @@ class AccessStats:
     ``tier2_hits`` the neighbour lookups tier 2 served inside beam
     phases (distance evaluations less misses; the hits of a layer's
     entry probe are not counted). ``host_syncs`` counts the blocking
-    device->host reads of those drivers and of ``TieredStore.gather``.
+    device->host reads of those drivers and of ``TieredStore.gather``
+    (``TieredStore.fill_batch`` makes none).
     """
 
     n_db: int = 0  # number of external accesses (transactions)
@@ -529,6 +548,8 @@ class TieredStore:
     ``gather(ids)``: look up tier 2; fetch only the misses from tier 3 in
     ONE access; insert them into tier 2; return all vectors. This is the
     bulk phase-2 load of the lazy search (Algorithm 1 line 24).
+    ``fill_batch(ids)`` is the batched driver's form for ids known to
+    miss: no lookup, and the rows stay on the device.
     """
 
     def __init__(
@@ -646,6 +667,37 @@ class TieredStore:
         union = np.unique(ids[valid])  # sorted — searchsorted below
         union_vecs = self.gather(union)
         out[valid] = union_vecs[np.searchsorted(union, ids[valid])]
+        return out
+
+    def fill_batch(self, ids: np.ndarray) -> jnp.ndarray:
+        """Batched miss fill for ids known to be absent (DESIGN.md §5).
+
+        ``ids`` is a (B, k) matrix of -1-padded per-query miss lists, and
+        every valid id in it must be absent from tier 2 at call time: the
+        batched driver's phase programs found each one missing in the
+        same tier-2 snapshot. So no lookup is made. The union is fetched
+        from tier 3 in ONE access, copied to the device once, inserted
+        and (under LRU) touched, and scattered back to per-row vectors by
+        one program. Returns those (B, k, d) float32 rows on the device
+        (padded rows zero): the values, ids, slots and clocks that
+        :meth:`gather_batch` gives on the same misses, with no
+        device->host read.
+        """
+        ids = np.asarray(ids, dtype=np.int32)
+        valid = ids >= 0
+        if not valid.any():
+            return jnp.zeros((*ids.shape, self.external.dim), jnp.float32)
+        union = np.unique(ids[valid])  # sorted — searchsorted below
+        padded = self._pad_pow2(union)
+        rows = np.zeros((len(padded), self.external.dim), np.float32)
+        rows[: len(union)] = self.external.fetch(union)
+        self.external.mark_used_ids(union)  # every gathered id is demanded
+        inv = np.full(ids.shape, -1, np.int32)
+        inv[valid] = np.searchsorted(union, ids[valid])
+        self.cache, out = _fill_union(
+            self.cache, jnp.asarray(padded), jnp.asarray(rows),
+            jnp.asarray(inv), policy=self.eviction,
+        )
         return out
 
     def warm(self, ids: np.ndarray) -> None:

@@ -9,8 +9,10 @@ import glob
 import os
 
 import jax
+import jax.numpy as jnp
 import pytest
 
+from repro.core import store as store_mod
 from repro.core.engine import EngineConfig, SearchRequest, WebANNSEngine
 from repro.core.spans import SPAN_NAMES
 from repro.core.store import AccessStats
@@ -142,6 +144,43 @@ def test_identical_runs_count_identical_syncs(small_dataset, small_graph):
         deltas.append(_delta(eng, before))
     assert deltas[0] == deltas[1]
     assert deltas[0]["host_syncs"] > 0
+
+
+def test_batched_fill_drops_two_syncs_per_gathered_phase(small_dataset,
+                                                        small_graph,
+                                                        monkeypatch):
+    """``fill_batch`` makes no device->host read, where ``gather_batch``
+    reads the looked-up presence and rows: two per gathered phase."""
+    X, Q = small_dataset
+    req = SearchRequest(query=Q[:4], k=10, ef=32)
+    ref = _engine(X, small_graph, len(X) // 4)
+    ref.store.fill_batch = lambda ids: jnp.asarray(
+        ref.store.gather_batch(ids))
+    before = ref.snapshot_access_stats()
+    want = ref.search(req)
+    want_syncs = _delta(ref, before)["host_syncs"]
+
+    eng = _engine(X, small_graph, len(X) // 4)
+    fill = eng.store.fill_batch
+    fill_syncs = []
+
+    def counted(ids):
+        n0 = eng.external.stats.host_syncs
+        out = fill(ids)
+        fill_syncs.append(eng.external.stats.host_syncs - n0)
+        return out
+
+    def no_read(x, stats):
+        raise AssertionError("fill_batch read the device")
+
+    eng.store.fill_batch = counted
+    monkeypatch.setattr(store_mod, "to_host", no_read)
+    before = eng.snapshot_access_stats()
+    got = eng.search(req)
+    n_phases = got.batch_stats.n_phases
+    assert n_phases == want.batch_stats.n_phases > 0
+    assert len(fill_syncs) == n_phases and not any(fill_syncs)
+    assert _delta(eng, before)["host_syncs"] == want_syncs - 2 * n_phases
 
 
 def test_fused_driver_leaves_the_phase_loop_counters(small_dataset,
