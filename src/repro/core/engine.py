@@ -291,13 +291,12 @@ class SearchResult:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("ef", "metric")
+    jax.jit, static_argnames=("ef", "miss_cap", "metric")
 )
-def _seed_cached(q, entry_ids, cache: CacheState, ef: int, miss_cap_arr,
+def _seed_cached(q, entry_ids, cache: CacheState, ef: int, miss_cap: int,
                  metric: str, tombs, banned):
     n = cache.slot_of.shape[0]
-    state = S.make_state(ef, miss_cap_arr.shape[0], n, tombstones=tombs,
-                         banned=banned)
+    state = S.make_state(ef, miss_cap, n, tombstones=tombs, banned=banned)
     lookup = lambda ids: cache_lookup(cache, ids)
     return S.seed_state(state, q, entry_ids, lookup, metric)
 
@@ -311,6 +310,22 @@ def _phase_cached(q, neighbors_l, state: S.SearchState, cache: CacheState,
     return S.search_phase(
         q, neighbors_l, state, lookup, metric, ef_trigger=ef_trigger
     )
+
+
+@functools.partial(jax.jit, static_argnames=("ef_upper",))
+def _descend(beam_ids, entry, walked, n_hops, n_dist, ef_upper: int):
+    """Next layer's (1,) entry, the first live id of the ``ef_upper``
+    best (else the old entry), and the running (hops, distances) of the
+    layers walked: the single-query descent reads nothing back."""
+    best = beam_ids[:ef_upper]
+    live = best >= 0
+    nxt = jnp.where(live.any(), best[jnp.argmax(live)], entry[0])
+    return nxt[None], _tally(walked, n_hops, n_dist)
+
+
+@jax.jit
+def _tally(walked, n_hops, n_dist):
+    return walked + jnp.stack([n_hops, n_dist])
 
 
 @functools.partial(jax.jit, static_argnames=("metric",))
@@ -441,7 +456,7 @@ class WebANNSEngine:
         self.store = TieredStore(self.external, cap, self.config.eviction,
                                  precision=self.config.precision,
                                  codebook=self.pq_codebook)
-        self.neighbors = jnp.asarray(graph.neighbors)
+        self._upload_graph()
         # Text-embedding separation (paper §4.1): texts live in a separate
         # id-indexed store, never loaded during queries.
         self.doc_store = DocStore(texts) if texts is not None else None
@@ -764,7 +779,7 @@ class WebANNSEngine:
             [self.tombstones, np.zeros(n_new, dtype=bool)]
         )
         self.n = self.external.n_items
-        self.neighbors = jnp.asarray(self.graph.neighbors)
+        self._upload_graph()
         self.store.grow(self.n)
         if texts is not None and self.doc_store is None:
             self.doc_store = DocStore([None] * (self.n - n_new))
@@ -997,21 +1012,28 @@ class WebANNSEngine:
 
     # ------------------------------------------------------------- query
 
+    def _upload_graph(self) -> None:
+        """The device's copy of the graph, whole (fused and sharded
+        drivers) and one array per layer (the phase loops), so that no
+        phase slices a layer out eagerly."""
+        self.neighbors = jnp.asarray(self.graph.neighbors)
+        self._layer_neighbors = [
+            jnp.asarray(nb) for nb in self.graph.neighbors
+        ]
+
     def _lazy_layer(
-        self, q: jnp.ndarray, layer: int, entry_ids: np.ndarray, ef: int,
+        self, q: jnp.ndarray, layer: int, entry: jnp.ndarray, ef: int,
         stats: QueryStats, eager: bool,
         banned: Optional[jnp.ndarray] = None,
     ) -> S.SearchState:
-        """Run one layer with phased lazy loading (or eager fetches)."""
+        """Run one layer with phased lazy loading (or eager fetches),
+        from the device-resident (1,) ``entry``."""
         cfg = self.config
         acc = self.external.stats
         miss_cap = ef + self.graph.max_degree + 1
         with span("seed"):
-            dummy = jnp.zeros((miss_cap,), jnp.int32)
-            entry_np = np.full(max(len(entry_ids), 1), -1, np.int32)
-            entry_np[: len(entry_ids)] = entry_ids
             state = _seed_cached(
-                q, jnp.asarray(entry_np), self.store.cache, ef, dummy,
+                q, entry, self.store.cache, ef, miss_cap,
                 cfg.metric, self._tombs_device(),
                 self._noban_device() if banned is None else banned,
             )
@@ -1022,7 +1044,7 @@ class WebANNSEngine:
             t0 = time.perf_counter()
             with span("beam_phase"):
                 state = _phase_cached(
-                    q, self.neighbors[layer], state, self.store.cache,
+                    q, self._layer_neighbors[layer], state, self.store.cache,
                     cfg.metric, trigger,
                 )
                 mc = int(to_host(state.miss_count, acc))
@@ -1036,25 +1058,21 @@ class WebANNSEngine:
             acc.tier2_misses += mc
             if mc == 0:
                 break
-            # ONE tier-3 access for the whole lazy list (Alg. 1 line 24)
+            # ONE tier-3 access for the whole lazy list (Alg. 1 line 24);
+            # the list is read whole, so no program is sized by mc
             with span("tier2_gather"):
-                miss_ids = to_host(state.miss_ids[:mc], acc)
-                db0 = acc.n_db
-                vecs = self.store.gather(miss_ids)
+                miss_np = to_host(state.miss_ids, acc)
+                db0, fetched0 = acc.n_db, acc.items_fetched
+                vecs = self.store.fill(miss_np)
             stats.n_db += acc.n_db - db0
-            stats.items_fetched += len(miss_ids)
+            stats.items_fetched += acc.items_fetched - fetched0
+            t0 = time.perf_counter()
             with span("load_phase"):
-                # pad host-side (fixed shapes → zero eager-op compiles)
-                padded_ids = np.full((miss_cap,), -1, np.int32)
-                padded_ids[:mc] = miss_ids
-                padded_vecs = np.zeros((miss_cap, self.dim), np.float32)
-                padded_vecs[:mc] = vecs
-                t0 = time.perf_counter()
+                # the miss ids and the filled rows are both device-resident
                 state = _load_cached(
-                    q, state, jnp.asarray(padded_ids),
-                    jnp.asarray(padded_vecs), cfg.metric,
+                    q, state, state.miss_ids, vecs, cfg.metric,
                 )
-                stats.t_in_mem += time.perf_counter() - t0
+            stats.t_in_mem += time.perf_counter() - t0
         return state
 
     def _batched_lazy_layer(
@@ -1091,7 +1109,7 @@ class WebANNSEngine:
             t0 = time.perf_counter()
             with span("beam_phase"):
                 states = _batch_phase_cached(
-                    Q, self.neighbors[layer], states, self.store.cache,
+                    Q, self._layer_neighbors[layer], states, self.store.cache,
                     cfg.metric, trigger,
                 )
                 mc = to_host(states.miss_count, acc)
@@ -1238,22 +1256,26 @@ class WebANNSEngine:
         stats = QueryStats()
         qj = jnp.asarray(q, jnp.float32)
         t_db0 = acc.modeled_time
-        entry = np.array([self.graph.entry_point], np.int32)
+        entry = jnp.asarray([self.graph.entry_point], jnp.int32)
+        walked = jnp.zeros((2,), jnp.int32)  # (hops, distances)
         # upper layers: beam of ef_upper (greedy for 1), lazily loaded too;
-        # the deny mask is irrelevant here (descent only routes)
+        # the deny mask is irrelevant here (descent only routes). The
+        # entry and the counts stay on the device: a layer costs its
+        # phases alone, however many layers the graph drew.
         for lc in range(self.graph.max_level, 0, -1):
             st = self._lazy_layer(qj, lc, entry, cfg.ef_upper, stats, eager)
             with span("descend"):
-                best = to_host(st.beam.ids[: cfg.ef_upper], acc)
-                entry = best[best >= 0][:1] if (best >= 0).any() else entry
-                stats.n_hops += int(to_host(st.n_hops, acc))
-                stats.n_dist += int(to_host(st.n_dist, acc))
+                entry, walked = _descend(
+                    st.beam.ids, entry, walked, st.n_hops, st.n_dist,
+                    cfg.ef_upper,
+                )
         st = self._lazy_layer(
             qj, 0, entry, max(ef, k), stats, eager, banned=banned
         )
         with span("finalize"):
-            stats.n_hops += int(to_host(st.n_hops, acc))
-            stats.n_dist += int(to_host(st.n_dist, acc))
+            hops, n_dist = to_host(_tally(walked, st.n_hops, st.n_dist), acc)
+            stats.n_hops += int(hops)
+            stats.n_dist += int(n_dist)
             stats.n_visited = stats.n_dist  # every visited id gets a distance
             if self._rerank_active():
                 pool = min(st.beam.ef, quant.rerank_pool(k, cfg.rerank_alpha))

@@ -17,10 +17,10 @@ SPAN_NAMES = (
     "search",  # WebANNSEngine.search: the whole call, every driver
     "seed",  # layer entry: seed-program dispatch
     "beam_phase",  # phase dispatch and the wait for its miss count
-    "tier2_gather",  # miss-id read, TieredStore.gather / fill_batch
+    "tier2_gather",  # miss-id read, TieredStore.fill / fill_batch
     "tier3_fetch",  # ExternalStore.fetch: the backend read
-    "load_phase",  # load-program dispatch (single-query: the rows' copy)
-    "descend",  # between-layer reads and the entry update
+    "load_phase",  # load-program dispatch (the rows are on the device)
+    "descend",  # between layers: the entry update (on the device)
     "finalize",  # the final top-k read
     "rerank",  # exact rerank of a quantized search (one tier-3 access)
 )

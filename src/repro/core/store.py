@@ -357,8 +357,8 @@ def _fill_union(
     policy: int,
 ) -> Tuple[CacheState, jnp.ndarray]:
     """Insert a union of known misses, touch it under LRU, and scatter
-    its rows back to the (B, k) miss lists: :meth:`TieredStore.fill_batch`'s
-    one device program."""
+    its rows back to the miss lists (``inv``'s shape): the one device
+    program of :meth:`TieredStore.fill_batch` and :meth:`TieredStore.fill`."""
     cache = cache_insert(cache, ids, rows, policy=policy)
     if policy == EVICT_LRU:
         cache = cache_touch(cache, ids)
@@ -381,8 +381,9 @@ class AccessStats:
     ``tier2_hits`` the neighbour lookups tier 2 served inside beam
     phases (distance evaluations less misses; the hits of a layer's
     entry probe are not counted). ``host_syncs`` counts the blocking
-    device->host reads of those drivers and of ``TieredStore.gather``
-    (``TieredStore.fill_batch`` makes none).
+    device->host reads of those drivers (one miss-id read per gathered
+    phase) and of ``TieredStore.gather``, which no driver calls
+    (``TieredStore.fill`` and ``fill_batch`` make none).
     """
 
     n_db: int = 0  # number of external accesses (transactions)
@@ -545,11 +546,13 @@ class ExternalStore:
 class TieredStore:
     """Tier 2 + tier 3 composition used by the engine driver.
 
-    ``gather(ids)``: look up tier 2; fetch only the misses from tier 3 in
-    ONE access; insert them into tier 2; return all vectors. This is the
-    bulk phase-2 load of the lazy search (Algorithm 1 line 24).
-    ``fill_batch(ids)`` is the batched driver's form for ids known to
-    miss: no lookup, and the rows stay on the device.
+    ``fill(ids)`` and ``fill_batch(ids)``: fetch ids known to miss tier
+    2 from tier 3 in ONE access, insert them, and return their rows on
+    the device. This is the bulk phase-2 load of the lazy search
+    (Algorithm 1 line 24): ``fill`` for the single-query driver,
+    ``fill_batch`` for the batched one. ``gather(ids)`` is the general
+    form for ids that may hit: it looks tier 2 up, fetches only the
+    misses, and returns all rows on the host.
     """
 
     def __init__(
@@ -609,18 +612,25 @@ class TieredStore:
     PAD_FLOOR = 64
 
     @staticmethod
+    def _bucket(n: int) -> int:
+        """The power-of-2 bucket (floored at :data:`PAD_FLOOR`) of ``n``."""
+        return max(TieredStore.PAD_FLOOR, 1 << (max(1, n) - 1).bit_length())
+
+    @staticmethod
     def _pad_pow2(ids: np.ndarray) -> np.ndarray:
         """Pad id batches to a SMALL fixed set of power-of-2 buckets
         (floored at :data:`PAD_FLOOR`) so the jitted cache ops trace once
         per bucket instead of once per novel batch size."""
-        n = max(1, len(ids))
-        cap = max(TieredStore.PAD_FLOOR, 1 << (n - 1).bit_length())
-        out = np.full(cap, -1, np.int32)
+        out = np.full(TieredStore._bucket(len(ids)), -1, np.int32)
         out[: len(ids)] = ids
         return out
 
     def gather(self, ids: np.ndarray) -> np.ndarray:
-        """Bulk gather with single-access miss fill. ids: (k,) no padding."""
+        """Bulk gather with single-access miss fill. ids: (k,) no padding.
+
+        No driver calls it: the lazy drivers hand the store known misses
+        (:meth:`fill`, :meth:`fill_batch`). :meth:`gather_batch` and the
+        tests, which hold the fills to it, do."""
         ids = np.asarray(ids, dtype=np.int32)
         k = len(ids)
         padded = self._pad_pow2(ids)
@@ -681,19 +691,48 @@ class TieredStore:
         one program. Returns those (B, k, d) float32 rows on the device
         (padded rows zero): the values, ids, slots and clocks that
         :meth:`gather_batch` gives on the same misses, with no
-        device->host read.
+        device->host read. The batched driver calls it.
         """
         ids = np.asarray(ids, dtype=np.int32)
         valid = ids >= 0
         if not valid.any():
             return jnp.zeros((*ids.shape, self.external.dim), jnp.float32)
         union = np.unique(ids[valid])  # sorted — searchsorted below
-        padded = self._pad_pow2(union)
-        rows = np.zeros((len(padded), self.external.dim), np.float32)
-        rows[: len(union)] = self.external.fetch(union)
-        self.external.mark_used_ids(union)  # every gathered id is demanded
         inv = np.full(ids.shape, -1, np.int32)
         inv[valid] = np.searchsorted(union, ids[valid])
+        return self._fill(union, self._bucket(len(union)), inv)
+
+    def fill(self, ids: np.ndarray) -> jnp.ndarray:
+        """Single-query miss fill for ids known to be absent (DESIGN.md §5).
+
+        ``ids`` is the single-query driver's whole -1-padded miss list,
+        of its fixed length ``miss_cap``; every valid id in it was found
+        missing by the phase program that listed it, and the ids are
+        distinct. The misses are fetched from tier 3 in ONE access and
+        filled by :meth:`fill_batch`'s program, inserted in list order,
+        so slots and clocks are those :meth:`gather` gives on the same
+        list. The union is padded to ``miss_cap``, not to the miss
+        count, so the programs this builds are fixed by the layer's
+        shapes. Returns the (miss_cap, d) float32 rows on the device in
+        list order (padded rows zero), with no device->host read.
+        """
+        ids = np.asarray(ids, dtype=np.int32)
+        valid = ids >= 0
+        if not valid.any():
+            return jnp.zeros((len(ids), self.external.dim), jnp.float32)
+        inv = np.full(ids.shape, -1, np.int32)
+        inv[valid] = np.arange(int(valid.sum()), dtype=np.int32)
+        return self._fill(ids[valid], len(ids), inv)
+
+    def _fill(self, union: np.ndarray, width: int,
+              inv: np.ndarray) -> jnp.ndarray:
+        """Fetch ``union`` in one access, pad it to ``width`` rows, and run
+        :func:`_fill_union`; returns the rows scattered by ``inv``."""
+        padded = np.full(width, -1, np.int32)
+        padded[: len(union)] = union
+        rows = np.zeros((width, self.external.dim), np.float32)
+        rows[: len(union)] = self.external.fetch(union)
+        self.external.mark_used_ids(union)  # every gathered id is demanded
         self.cache, out = _fill_union(
             self.cache, jnp.asarray(padded), jnp.asarray(rows),
             jnp.asarray(inv), policy=self.eviction,

@@ -10,6 +10,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import store as store_mod
@@ -181,6 +182,70 @@ def test_batched_fill_drops_two_syncs_per_gathered_phase(small_dataset,
     assert n_phases == want.batch_stats.n_phases > 0
     assert len(fill_syncs) == n_phases and not any(fill_syncs)
     assert _delta(eng, before)["host_syncs"] == want_syncs - 2 * n_phases
+
+
+def test_single_fill_drops_two_syncs_per_gathered_phase(small_dataset,
+                                                       small_graph,
+                                                       monkeypatch):
+    """``fill`` makes no device->host read, where ``gather`` reads the
+    looked-up presence and rows: two per gathered single-query phase.
+    The miss count and the miss-id read still count, once a phase."""
+    X, Q = small_dataset
+    req = SearchRequest(query=Q[0], k=10, ef=32)
+    ref = _engine(X, small_graph, len(X) // 4)
+
+    def gather_fill(ids):
+        out = np.zeros((len(ids), X.shape[1]), np.float32)
+        out[: (ids >= 0).sum()] = ref.store.gather(ids[ids >= 0])
+        return jnp.asarray(out)
+
+    ref.store.fill = gather_fill
+    before = ref.snapshot_access_stats()
+    want = ref.search(req)
+    want_d = _delta(ref, before)
+
+    eng = _engine(X, small_graph, len(X) // 4)
+    fill = eng.store.fill
+    misses = []
+
+    def counted(ids):
+        n0 = eng.external.stats.host_syncs
+        out = fill(ids)
+        assert eng.external.stats.host_syncs == n0
+        misses.append(int((ids >= 0).sum()))
+        return out
+
+    def no_read(x, stats):
+        raise AssertionError("fill read the device")
+
+    eng.store.fill = counted
+    monkeypatch.setattr(store_mod, "to_host", no_read)
+    before = eng.snapshot_access_stats()
+    got = eng.search(req)
+    d = _delta(eng, before)
+    n_phases = len(misses)
+    assert n_phases == got.stats.n_db == want.stats.n_db > 0
+    assert d["host_syncs"] == want_d["host_syncs"] - 2 * n_phases
+    assert d["tier2_misses"] == sum(misses) == want_d["tier2_misses"]
+    assert d["tier2_hits"] == want_d["tier2_hits"] > 0
+
+
+@pytest.mark.parametrize("eviction", ["fifo", "lru"])
+def test_single_descent_reads_nothing_back(small_dataset, small_graph,
+                                           eviction):
+    """A single-query search reads the miss count once a phase and the
+    miss ids once a gathered phase; between layers it reads nothing, and
+    it ends with one read of its counts and two of its answer."""
+    X, Q = small_dataset
+    eng = _engine(X, small_graph, len(X) // 4, eviction=eviction)
+    assert small_graph.n_layers > 1
+    for q in Q[:3]:
+        before = eng.snapshot_access_stats()
+        eng.search(SearchRequest(query=q, k=10, ef=32))
+        d = _delta(eng, before)
+        # each layer's phases: one per gathered phase, plus the last
+        phases = d["n_db"] + small_graph.n_layers
+        assert d["host_syncs"] == phases + d["n_db"] + 3
 
 
 def test_fused_driver_leaves_the_phase_loop_counters(small_dataset,
