@@ -79,6 +79,7 @@ from repro.core.store import (
     TieredStore,
     cache_lookup,
     cache_touch,
+    fill_union,
 )
 
 
@@ -166,7 +167,7 @@ class EngineConfig:
     t_setup: float = 1.0e-3
     t_per_item: float = 2.0e-6
     simulate_latency: bool = False
-    max_phases: int = 10000  # safety bound on lazy phase loop
+    max_phases: int = 10000  # safety bound on a layer's beam phases
     # fused=True runs the WHOLE lazy query (phases + bulk loads + cache
     # updates) as one jitted program (search.lazy_knn_search_fused) with
     # the tier-3 payload device-resident — the TPU-native endpoint;
@@ -287,29 +288,65 @@ class SearchResult:
 
 
 # --------------------------------------------------------------- jit phases
-# Cache state is an explicit argument so phases trace once per (shape, ef).
+# The phase loops' programs. Each ends one beam phase of Algorithm 1 and
+# returns ``(cache, state)``: the cache is an explicit argument, so each
+# traces once per shape, ef and layer width. ``q`` is one (d,) query or
+# a (B, d) batch; the batch's phases are vmapped against one shared
+# tier-2 snapshot, so its misses are comparable and unionable (§5).
+
+
+def _end_phase(q, neighbors_l, state: S.SearchState, cache: CacheState,
+               metric: str, ef_trigger: int, policy: int):
+    """One beam phase against ``cache`` and, under LRU, the touch of the
+    beam at the phase boundary (in-phase hits can't touch in-graph)."""
+    lookup = lambda ids: cache_lookup(cache, ids)
+    phase = S.search_phase if q.ndim == 1 else S.batch_search_phase
+    state = phase(q, neighbors_l, state, lookup, metric,
+                  ef_trigger=ef_trigger)
+    if policy == EVICT_LRU:
+        cache = cache_touch(cache, state.beam.ids.reshape(-1))
+    return cache, state
 
 
 @functools.partial(
-    jax.jit, static_argnames=("ef", "miss_cap", "metric")
+    jax.jit,
+    static_argnames=("ef", "miss_cap", "metric", "ef_trigger", "policy"),
 )
-def _seed_cached(q, entry_ids, cache: CacheState, ef: int, miss_cap: int,
-                 metric: str, tombs, banned):
+def _enter_layer(q, entry_ids, neighbors_l, cache: CacheState, tombs, banned,
+                 ef: int, miss_cap: int, metric: str, ef_trigger: int,
+                 policy: int):
+    """Layer entry: seed a fresh state from ``entry_ids`` (misses go to
+    the lazy list) and run the layer's first phase."""
     n = cache.slot_of.shape[0]
-    state = S.make_state(ef, miss_cap, n, tombstones=tombs, banned=banned)
     lookup = lambda ids: cache_lookup(cache, ids)
-    return S.seed_state(state, q, entry_ids, lookup, metric)
+    if q.ndim == 1:
+        state = S.seed_state(
+            S.make_state(ef, miss_cap, n, tombstones=tombs, banned=banned),
+            q, entry_ids, lookup, metric,
+        )
+    else:
+        state = S.batch_seed_state(
+            S.batch_make_state(q.shape[0], ef, miss_cap, n,
+                               tombstones=tombs, banned=banned),
+            q, entry_ids, lookup, metric,
+        )
+    return _end_phase(q, neighbors_l, state, cache, metric, ef_trigger,
+                      policy)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("metric", "ef_trigger")
-)
-def _phase_cached(q, neighbors_l, state: S.SearchState, cache: CacheState,
-                  metric: str, ef_trigger: int):
-    lookup = lambda ids: cache_lookup(cache, ids)
-    return S.search_phase(
-        q, neighbors_l, state, lookup, metric, ef_trigger=ef_trigger
-    )
+@functools.partial(jax.jit, static_argnames=("metric", "ef_trigger", "policy"))
+def _gathered_phase(q, neighbors_l, state: S.SearchState, cache: CacheState,
+                    idx, rows, metric: str, ef_trigger: int, policy: int):
+    """A gathered phase: fill the misses that ``TieredStore.stage`` /
+    ``stage_batch`` fetched (``idx``, ``rows``) into tier 2, merge them
+    into the beam and clear the lazy list (Alg. 1 lines 24–31), then run
+    the next phase against the filled cache."""
+    cache, vecs = fill_union(cache, idx, rows, state.miss_ids.shape,
+                             policy=policy)
+    load = S.load_phase if q.ndim == 1 else S.batch_load_phase
+    state = load(q, state, state.miss_ids, vecs, metric)
+    return _end_phase(q, neighbors_l, state, cache, metric, ef_trigger,
+                      policy)
 
 
 @functools.partial(jax.jit, static_argnames=("ef_upper",))
@@ -328,51 +365,9 @@ def _tally(walked, n_hops, n_dist):
     return walked + jnp.stack([n_hops, n_dist])
 
 
-@functools.partial(jax.jit, static_argnames=("metric",))
-def _load_cached(q, state: S.SearchState, loaded_ids, loaded_vecs,
-                 metric: str):
-    return S.load_phase(q, state, loaded_ids, loaded_vecs, metric)
-
-
-# ------------------------------------------------------ jit batched phases
-# vmapped counterparts used by the batched driver (DESIGN.md §5). The
-# cache is an explicit broadcast argument: all B queries probe the same
-# tier-2 snapshot within a phase, so misses are comparable and unionable.
-
-
-@functools.partial(
-    jax.jit, static_argnames=("ef", "miss_cap", "metric")
-)
-def _batch_seed_cached(Q, entry_ids, cache: CacheState, ef: int,
-                       miss_cap: int, metric: str, tombs, banned):
-    n = cache.slot_of.shape[0]
-    lookup = lambda ids: cache_lookup(cache, ids)
-    states = S.batch_make_state(
-        Q.shape[0], ef, miss_cap, n, tombstones=tombs, banned=banned
-    )
-    return S.batch_seed_state(states, Q, entry_ids, lookup, metric)
-
-
 @functools.partial(jax.jit, static_argnames=("k",))
 def _finalize_cached(state: S.SearchState, k: int):
     return S.finalize_topk(state, k)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("metric", "ef_trigger")
-)
-def _batch_phase_cached(Q, neighbors_l, states: S.SearchState,
-                        cache: CacheState, metric: str, ef_trigger: int):
-    lookup = lambda ids: cache_lookup(cache, ids)
-    return S.batch_search_phase(
-        Q, neighbors_l, states, lookup, metric, ef_trigger=ef_trigger
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("metric",))
-def _batch_load_cached(Q, states: S.SearchState, loaded_ids, loaded_vecs,
-                       metric: str):
-    return S.batch_load_phase(Q, states, loaded_ids, loaded_vecs, metric)
 
 
 class WebANNSEngine:
@@ -1027,51 +1022,44 @@ class WebANNSEngine:
         banned: Optional[jnp.ndarray] = None,
     ) -> S.SearchState:
         """Run one layer with phased lazy loading (or eager fetches),
-        from the device-resident (1,) ``entry``."""
+        from the device-resident (1,) ``entry``: one program and one read
+        of the whole miss list per phase (DESIGN.md §5)."""
         cfg = self.config
         acc = self.external.stats
+        store = self.store
         miss_cap = ef + self.graph.max_degree + 1
-        with span("seed"):
-            state = _seed_cached(
-                q, entry, self.store.cache, ef, miss_cap,
-                cfg.metric, self._tombs_device(),
-                self._noban_device() if banned is None else banned,
-            )
         # eager mode (webanns-base): trigger=1 → flush L after every miss
         trigger = 1 if eager else ef
-
-        for _ in range(cfg.max_phases):
-            t0 = time.perf_counter()
-            with span("beam_phase"):
-                state = _phase_cached(
-                    q, self._layer_neighbors[layer], state, self.store.cache,
-                    cfg.metric, trigger,
-                )
-                mc = int(to_host(state.miss_count, acc))
-                if self.store.eviction == EVICT_LRU:
-                    # phase-boundary touch: the beam approximates the
-                    # recently-used set (in-phase hits can't touch in-graph)
-                    self.store.cache = cache_touch(
-                        self.store.cache, state.beam.ids
-                    )
-            stats.t_in_mem += time.perf_counter() - t0
+        neighbors_l = self._layer_neighbors[layer]
+        t0 = time.perf_counter()
+        with span("seed"):
+            store.cache, state = _enter_layer(
+                q, entry, neighbors_l, store.cache, self._tombs_device(),
+                self._noban_device() if banned is None else banned,
+                ef, miss_cap, cfg.metric, trigger, store.eviction,
+            )
+            miss_np = to_host(state.miss_ids, acc)
+        stats.t_in_mem += time.perf_counter() - t0
+        for phase in range(1, cfg.max_phases + 1):  # 1 ran in the entry
+            # the list holds its misses first and -1 after them
+            mc = int((miss_np >= 0).sum())
             acc.tier2_misses += mc
-            if mc == 0:
+            if mc == 0 or phase == cfg.max_phases:
                 break
             # ONE tier-3 access for the whole lazy list (Alg. 1 line 24);
-            # the list is read whole, so no program is sized by mc
+            # the list goes whole, so no program is sized by mc
             with span("tier2_gather"):
-                miss_np = to_host(state.miss_ids, acc)
                 db0, fetched0 = acc.n_db, acc.items_fetched
-                vecs = self.store.fill(miss_np)
+                idx, rows = store.stage(miss_np)
             stats.n_db += acc.n_db - db0
             stats.items_fetched += acc.items_fetched - fetched0
             t0 = time.perf_counter()
-            with span("load_phase"):
-                # the miss ids and the filled rows are both device-resident
-                state = _load_cached(
-                    q, state, state.miss_ids, vecs, cfg.metric,
+            with span("beam_phase"):
+                store.cache, state = _gathered_phase(
+                    q, neighbors_l, state, store.cache, idx, rows,
+                    cfg.metric, trigger, store.eviction,
                 )
+                miss_np = to_host(state.miss_ids, acc)
             stats.t_in_mem += time.perf_counter() - t0
         return state
 
@@ -1085,49 +1073,39 @@ class WebANNSEngine:
         All B queries advance one in-memory phase together (vmapped
         against the same tier-2 snapshot); their miss lists are unioned,
         deduplicated, and satisfied by ONE tier-3 access per phase for
-        the whole batch; the bulk load is scattered back per query on
-        the device (``TieredStore.fill_batch``: every listed id missed
-        that same snapshot, so the store looks none of them up again).
+        the whole batch (``TieredStore.stage_batch``: every listed id
+        missed that same snapshot, so the store looks none of them up
+        again). The next program fills the union on the device and
+        scatters it back per query before the next phase.
         """
         cfg = self.config
         acc = self.external.stats
+        store = self.store
         miss_cap = ef + self.graph.max_degree + 1
         trigger = 1 if eager else ef
+        neighbors_l = self._layer_neighbors[layer]
 
         t0 = time.perf_counter()
         with span("seed"):
-            if banned is None:
-                banned = jnp.broadcast_to(
-                    self._noban_device(), (Q.shape[0], self.n)
-                )
-            states = _batch_seed_cached(
-                Q, jnp.asarray(entry_ids), self.store.cache, ef, miss_cap,
-                cfg.metric, self._tombs_device(), banned,
+            # an (N,) mask is broadcast over the batch inside the program
+            store.cache, states = _enter_layer(
+                Q, entry_ids, neighbors_l, store.cache, self._tombs_device(),
+                self._noban_device() if banned is None else banned,
+                ef, miss_cap, cfg.metric, trigger, store.eviction,
             )
+            miss_np = to_host(states.miss_ids, acc)
         bstats.t_in_mem += time.perf_counter() - t0
-        for _ in range(cfg.max_phases):
-            t0 = time.perf_counter()
-            with span("beam_phase"):
-                states = _batch_phase_cached(
-                    Q, self._layer_neighbors[layer], states, self.store.cache,
-                    cfg.metric, trigger,
-                )
-                mc = to_host(states.miss_count, acc)
-                if self.store.eviction == EVICT_LRU:
-                    self.store.cache = cache_touch(
-                        self.store.cache, states.beam.ids.reshape(-1)
-                    )
-            bstats.t_in_mem += time.perf_counter() - t0
+        for phase in range(1, cfg.max_phases + 1):  # 1 ran in the entry
+            mc = (miss_np >= 0).sum(-1)  # each list: misses, then -1
             n_miss = int(mc.sum())
             acc.tier2_misses += n_miss
-            if n_miss == 0:
+            if n_miss == 0 or phase == cfg.max_phases:
                 break
             # ONE tier-3 access for the union of all B miss lists
             with span("tier2_gather"):
-                miss_np = to_host(states.miss_ids, acc)
                 db0 = acc.n_db
                 fetched0 = acc.items_fetched
-                vecs = self.store.fill_batch(miss_np)
+                idx, rows = store.stage_batch(miss_np)
             bstats.n_db += acc.n_db - db0
             bstats.items_fetched += acc.items_fetched - fetched0
             bstats.n_phases += 1
@@ -1136,11 +1114,12 @@ class WebANNSEngine:
                 per_stats[b].n_db += 1
                 per_stats[b].items_fetched += int(mc[b])
             t0 = time.perf_counter()
-            with span("load_phase"):
-                # the miss ids and the filled rows are both device-resident
-                states = _batch_load_cached(
-                    Q, states, states.miss_ids, vecs, cfg.metric,
+            with span("beam_phase"):
+                store.cache, states = _gathered_phase(
+                    Q, neighbors_l, states, store.cache, idx, rows,
+                    cfg.metric, trigger, store.eviction,
                 )
+                miss_np = to_host(states.miss_ids, acc)
             bstats.t_in_mem += time.perf_counter() - t0
         return states
 

@@ -15,11 +15,10 @@ import numpy as np
 # every span the program writes, in the order a request meets them
 SPAN_NAMES = (
     "search",  # WebANNSEngine.search: the whole call, every driver
-    "seed",  # layer entry: seed-program dispatch
-    "beam_phase",  # phase dispatch and the wait for its miss count
-    "tier2_gather",  # miss-id read, TieredStore.fill / fill_batch
+    "seed",  # layer entry: seed-and-first-phase dispatch, miss-list read
+    "tier2_gather",  # TieredStore.stage / stage_batch: union, fetch, pack
     "tier3_fetch",  # ExternalStore.fetch: the backend read
-    "load_phase",  # load-program dispatch (the rows are on the device)
+    "beam_phase",  # fill-load-phase dispatch and its miss-list read
     "descend",  # between layers: the entry update (on the device)
     "finalize",  # the final top-k read
     "rerank",  # exact rerank of a quantized search (one tier-3 access)

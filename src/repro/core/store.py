@@ -348,17 +348,23 @@ def cache_insert(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("policy",))
-def _fill_union(
+@functools.partial(jax.jit, static_argnames=("shape", "policy"))
+def fill_union(
     cache: CacheState,
-    ids: jnp.ndarray,  # (u,) int32 sorted union, -1 padded
+    idx: jnp.ndarray,  # (u + prod(shape),) int32: the union, then ``inv``
     rows: jnp.ndarray,  # (u, d) float32 tier-3 rows, zero padded
-    inv: jnp.ndarray,  # (B, k) int32 row of each miss in ``ids``, -1 padded
+    shape: Tuple[int, ...],  # of the miss lists
     policy: int,
 ) -> Tuple[CacheState, jnp.ndarray]:
     """Insert a union of known misses, touch it under LRU, and scatter
-    its rows back to the miss lists (``inv``'s shape): the one device
-    program of :meth:`TieredStore.fill_batch` and :meth:`TieredStore.fill`."""
+    its rows back to the miss lists: the device half of
+    :meth:`TieredStore.fill` and :meth:`TieredStore.fill_batch`, whose
+    staging packs ``idx`` as the -1-padded union (``u`` ids, as many as
+    ``rows``) followed by ``inv``, each miss's row in the union (-1
+    padded), flattened. The lazy drivers call it inside their phase
+    programs. Returns the cache and the ``shape + (d,)`` rows."""
+    u = rows.shape[0]
+    ids, inv = idx[:u], idx[u:].reshape(shape)
     cache = cache_insert(cache, ids, rows, policy=policy)
     if policy == EVICT_LRU:
         cache = cache_touch(cache, ids)
@@ -381,9 +387,9 @@ class AccessStats:
     ``tier2_hits`` the neighbour lookups tier 2 served inside beam
     phases (distance evaluations less misses; the hits of a layer's
     entry probe are not counted). ``host_syncs`` counts the blocking
-    device->host reads of those drivers (one miss-id read per gathered
-    phase) and of ``TieredStore.gather``, which no driver calls
-    (``TieredStore.fill`` and ``fill_batch`` make none).
+    device->host reads of those drivers (one miss-list read per phase
+    program) and of ``TieredStore.gather``, which no driver calls
+    (the fills and their staging make none).
     """
 
     n_db: int = 0  # number of external accesses (transactions)
@@ -549,10 +555,12 @@ class TieredStore:
     ``fill(ids)`` and ``fill_batch(ids)``: fetch ids known to miss tier
     2 from tier 3 in ONE access, insert them, and return their rows on
     the device. This is the bulk phase-2 load of the lazy search
-    (Algorithm 1 line 24): ``fill`` for the single-query driver,
-    ``fill_batch`` for the batched one. ``gather(ids)`` is the general
-    form for ids that may hit: it looks tier 2 up, fetches only the
-    misses, and returns all rows on the host.
+    (Algorithm 1 line 24), of one miss list or of B. Each is a host
+    half (``stage`` / ``stage_batch``: union, fetch, padding) and a
+    device half (:func:`fill_union`); the lazy drivers call the halves,
+    the second inside their phase programs. ``gather(ids)`` is the
+    general form for ids that may hit: it looks tier 2 up, fetches only
+    the misses, and returns all rows on the host.
     """
 
     def __init__(
@@ -629,8 +637,8 @@ class TieredStore:
         """Bulk gather with single-access miss fill. ids: (k,) no padding.
 
         No driver calls it: the lazy drivers hand the store known misses
-        (:meth:`fill`, :meth:`fill_batch`). :meth:`gather_batch` and the
-        tests, which hold the fills to it, do."""
+        (:meth:`stage`, :meth:`stage_batch`). :meth:`gather_batch` and
+        the tests, which hold the fills to it, do."""
         ids = np.asarray(ids, dtype=np.int32)
         k = len(ids)
         padded = self._pad_pow2(ids)
@@ -683,24 +691,20 @@ class TieredStore:
         """Batched miss fill for ids known to be absent (DESIGN.md §5).
 
         ``ids`` is a (B, k) matrix of -1-padded per-query miss lists, and
-        every valid id in it must be absent from tier 2 at call time: the
-        batched driver's phase programs found each one missing in the
-        same tier-2 snapshot. So no lookup is made. The union is fetched
-        from tier 3 in ONE access, copied to the device once, inserted
-        and (under LRU) touched, and scattered back to per-row vectors by
-        one program. Returns those (B, k, d) float32 rows on the device
-        (padded rows zero): the values, ids, slots and clocks that
-        :meth:`gather_batch` gives on the same misses, with no
-        device->host read. The batched driver calls it.
+        every valid id in it must be absent from tier 2 at call time. So
+        no lookup is made: :meth:`stage_batch` fetches the union from
+        tier 3 in ONE access, and :func:`fill_union`, one program,
+        inserts and (under LRU) touches it and scatters it back to
+        per-row vectors. Returns those (B, k, d) float32 rows on the
+        device (padded rows zero): the values, ids, slots and clocks
+        that :meth:`gather_batch` gives on the same misses, with no
+        device->host read. The batched driver runs the same two halves,
+        with the device half inside its phase program, not this method.
         """
         ids = np.asarray(ids, dtype=np.int32)
-        valid = ids >= 0
-        if not valid.any():
+        if not (ids >= 0).any():
             return jnp.zeros((*ids.shape, self.external.dim), jnp.float32)
-        union = np.unique(ids[valid])  # sorted — searchsorted below
-        inv = np.full(ids.shape, -1, np.int32)
-        inv[valid] = np.searchsorted(union, ids[valid])
-        return self._fill(union, self._bucket(len(union)), inv)
+        return self._fill(self.stage_batch(ids), ids.shape)
 
     def fill(self, ids: np.ndarray) -> jnp.ndarray:
         """Single-query miss fill for ids known to be absent (DESIGN.md §5).
@@ -708,36 +712,61 @@ class TieredStore:
         ``ids`` is the single-query driver's whole -1-padded miss list,
         of its fixed length ``miss_cap``; every valid id in it was found
         missing by the phase program that listed it, and the ids are
-        distinct. The misses are fetched from tier 3 in ONE access and
-        filled by :meth:`fill_batch`'s program, inserted in list order,
-        so slots and clocks are those :meth:`gather` gives on the same
-        list. The union is padded to ``miss_cap``, not to the miss
-        count, so the programs this builds are fixed by the layer's
-        shapes. Returns the (miss_cap, d) float32 rows on the device in
-        list order (padded rows zero), with no device->host read.
+        distinct. :meth:`stage` fetches them from tier 3 in ONE access,
+        and :func:`fill_union` inserts them in list order, so slots and
+        clocks are those :meth:`gather` gives on the same list. Returns
+        the (miss_cap, d) float32 rows on the device in list order
+        (padded rows zero), with no device->host read. The single-query
+        driver runs the same two halves, with the device half inside
+        its phase program, not this method.
         """
         ids = np.asarray(ids, dtype=np.int32)
-        valid = ids >= 0
-        if not valid.any():
+        if not (ids >= 0).any():
             return jnp.zeros((len(ids), self.external.dim), jnp.float32)
+        return self._fill(self.stage(ids), ids.shape)
+
+    def _fill(self, staged: Tuple[np.ndarray, np.ndarray],
+              shape: Tuple[int, ...]) -> jnp.ndarray:
+        self.cache, out = fill_union(self.cache, *staged, shape=shape,
+                                     policy=self.eviction)
+        return out
+
+    def stage_batch(self, ids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """The host half of :meth:`fill_batch`: the sorted union of the
+        (B, k) miss lists, each miss's row in it, and ONE tier-3 access
+        for it, as :func:`fill_union`'s ``(idx, rows)``. The union is
+        padded to its power-of-two bucket, which is never wider than
+        the bucket of B × k."""
+        ids = np.asarray(ids, dtype=np.int32)
+        valid = ids >= 0
+        union = np.unique(ids[valid])  # sorted — searchsorted below
+        inv = np.full(ids.shape, -1, np.int32)
+        inv[valid] = np.searchsorted(union, ids[valid])
+        return self._stage(union, self._bucket(len(union)), inv)
+
+    def stage(self, ids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """The host half of :meth:`fill`: the list's misses in list
+        order, padded to the list's length, so the programs that take
+        them are fixed by the layer's shapes, whatever the miss count;
+        and ONE tier-3 access for them."""
+        ids = np.asarray(ids, dtype=np.int32)
+        valid = ids >= 0
         inv = np.full(ids.shape, -1, np.int32)
         inv[valid] = np.arange(int(valid.sum()), dtype=np.int32)
-        return self._fill(ids[valid], len(ids), inv)
+        return self._stage(ids[valid], len(ids), inv)
 
-    def _fill(self, union: np.ndarray, width: int,
-              inv: np.ndarray) -> jnp.ndarray:
-        """Fetch ``union`` in one access, pad it to ``width`` rows, and run
-        :func:`_fill_union`; returns the rows scattered by ``inv``."""
-        padded = np.full(width, -1, np.int32)
-        padded[: len(union)] = union
+    def _stage(self, union: np.ndarray, width: int,
+               inv: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """Fetch ``union`` in one access and pack it, padded to ``width``
+        rows, with ``inv`` into one int32 array: two host->device copies
+        a fill, not three."""
+        idx = np.full(width + inv.size, -1, np.int32)
+        idx[: len(union)] = union
+        idx[width:] = inv.ravel()
         rows = np.zeros((width, self.external.dim), np.float32)
         rows[: len(union)] = self.external.fetch(union)
         self.external.mark_used_ids(union)  # every gathered id is demanded
-        self.cache, out = _fill_union(
-            self.cache, jnp.asarray(padded), jnp.asarray(rows),
-            jnp.asarray(inv), policy=self.eviction,
-        )
-        return out
+        return idx, rows
 
     def warm(self, ids: np.ndarray) -> None:
         """Pre-populate tier 2 (initialization-stage index loading).

@@ -1,13 +1,14 @@
 """The single-query miss fill, ``TieredStore.fill`` (DESIGN.md §5).
 
 The single-query lazy driver reads its whole fixed-length miss list and
-hands it to the store, which fills the misses on the device with the
-batched fill's program. Three contracts:
+hands it to the store (``stage``), and its next phase program fills the
+misses on the device with the batched fill's device half. Three
+contracts:
 
-1. **Parity** — single-query searches through ``fill`` return the ids
-   and distances, and leave the whole tier-2 ``CacheState``, EQUAL to
-   the same searches through the general ``gather`` path, after every
-   query.
+1. **Parity** — single-query searches through the driver's fill return
+   the ids and distances, and leave the whole tier-2 ``CacheState``,
+   EQUAL to the same searches through the general ``gather`` path,
+   after every query.
 2. **No program in the window** — once warmed, new queries build no
    program: the fill's shapes are fixed by the layer's ``miss_cap``,
    whatever the miss count.
@@ -23,6 +24,7 @@ from repro.core.engine import EngineConfig, SearchRequest, WebANNSEngine
 from repro.core.eval import brute_force_topk, recall_at_k
 from repro.core.hnsw import build_hnsw
 from repro.core.metadata import Filter
+from test_phase_loops import use_reference_loop
 
 # (metric, eviction, precision, mode, tier-2 capacity as a share of N,
 # filtered)
@@ -58,8 +60,10 @@ def _engine(X, graphs, case):
     ), metadata=meta)
 
 
-def _gather_reference(store):
-    """Route the driver's fill through the general path it replaced."""
+def _gather_reference(engine):
+    """Run the reference loop, its fill through the general path."""
+    use_reference_loop(engine)
+    store = engine.store
 
     def fill(ids):
         miss = ids[ids >= 0]
@@ -71,15 +75,16 @@ def _gather_reference(store):
 
 
 def _record(store):
-    """Record each fill's list length and miss count, calling through."""
+    """Record each fill's list length and miss count, calling through to
+    the staging."""
     calls = []
-    fill = store.fill
+    stage = store.stage
 
     def recorded(ids):
         calls.append((len(ids), int((ids >= 0).sum())))
-        return fill(ids)
+        return stage(ids)
 
-    store.fill = recorded
+    store.stage = recorded
     return calls
 
 
@@ -98,7 +103,7 @@ def test_fill_matches_gather(small_dataset, graphs, case):
     X, Q = small_dataset
     filt = Filter.eq("user", 1) if CASES[case][5] else None
     ref = _engine(X, graphs, case)
-    _gather_reference(ref.store)
+    _gather_reference(ref)
     eng = _engine(X, graphs, case)
     calls = _record(eng.store)
     for q in (Q[0], Q[1], Q[0], Q[2]):  # the third query meets warm rows

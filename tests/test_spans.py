@@ -17,10 +17,11 @@ from repro.core import store as store_mod
 from repro.core.engine import EngineConfig, SearchRequest, WebANNSEngine
 from repro.core.spans import SPAN_NAMES
 from repro.core.store import AccessStats
+from test_phase_loops import use_reference_loop
 
 COUNTERS = ("tier2_hits", "tier2_misses", "host_syncs")
-INSIDE_SEARCH = ("seed", "beam_phase", "tier2_gather", "load_phase",
-                 "descend", "finalize")
+INSIDE_SEARCH = ("seed", "beam_phase", "tier2_gather", "descend",
+                 "finalize")
 
 
 def _engine(X, g, cap, **cfg):
@@ -150,11 +151,13 @@ def test_identical_runs_count_identical_syncs(small_dataset, small_graph):
 def test_batched_fill_drops_two_syncs_per_gathered_phase(small_dataset,
                                                         small_graph,
                                                         monkeypatch):
-    """``fill_batch`` makes no device->host read, where ``gather_batch``
-    reads the looked-up presence and rows: two per gathered phase."""
+    """The batched staging makes no device->host read, where
+    ``gather_batch`` reads the looked-up presence and rows: two per
+    gathered phase. The phase program's one miss-list read takes the
+    place of the miss-count and miss-id reads: one more."""
     X, Q = small_dataset
     req = SearchRequest(query=Q[:4], k=10, ef=32)
-    ref = _engine(X, small_graph, len(X) // 4)
+    ref = use_reference_loop(_engine(X, small_graph, len(X) // 4))
     ref.store.fill_batch = lambda ids: jnp.asarray(
         ref.store.gather_batch(ids))
     before = ref.snapshot_access_stats()
@@ -162,37 +165,38 @@ def test_batched_fill_drops_two_syncs_per_gathered_phase(small_dataset,
     want_syncs = _delta(ref, before)["host_syncs"]
 
     eng = _engine(X, small_graph, len(X) // 4)
-    fill = eng.store.fill_batch
-    fill_syncs = []
+    stage = eng.store.stage_batch
+    stage_syncs = []
 
     def counted(ids):
         n0 = eng.external.stats.host_syncs
-        out = fill(ids)
-        fill_syncs.append(eng.external.stats.host_syncs - n0)
+        out = stage(ids)
+        stage_syncs.append(eng.external.stats.host_syncs - n0)
         return out
 
     def no_read(x, stats):
-        raise AssertionError("fill_batch read the device")
+        raise AssertionError("stage_batch read the device")
 
-    eng.store.fill_batch = counted
+    eng.store.stage_batch = counted
     monkeypatch.setattr(store_mod, "to_host", no_read)
     before = eng.snapshot_access_stats()
     got = eng.search(req)
     n_phases = got.batch_stats.n_phases
     assert n_phases == want.batch_stats.n_phases > 0
-    assert len(fill_syncs) == n_phases and not any(fill_syncs)
-    assert _delta(eng, before)["host_syncs"] == want_syncs - 2 * n_phases
+    assert len(stage_syncs) == n_phases and not any(stage_syncs)
+    assert _delta(eng, before)["host_syncs"] == want_syncs - 3 * n_phases
 
 
 def test_single_fill_drops_two_syncs_per_gathered_phase(small_dataset,
                                                        small_graph,
                                                        monkeypatch):
-    """``fill`` makes no device->host read, where ``gather`` reads the
-    looked-up presence and rows: two per gathered single-query phase.
-    The miss count and the miss-id read still count, once a phase."""
+    """The single-query staging makes no device->host read, where
+    ``gather`` reads the looked-up presence and rows: two per gathered
+    phase. The phase program's one miss-list read takes the place of
+    the miss-count and miss-id reads: one more."""
     X, Q = small_dataset
     req = SearchRequest(query=Q[0], k=10, ef=32)
-    ref = _engine(X, small_graph, len(X) // 4)
+    ref = use_reference_loop(_engine(X, small_graph, len(X) // 4))
 
     def gather_fill(ids):
         out = np.zeros((len(ids), X.shape[1]), np.float32)
@@ -205,27 +209,27 @@ def test_single_fill_drops_two_syncs_per_gathered_phase(small_dataset,
     want_d = _delta(ref, before)
 
     eng = _engine(X, small_graph, len(X) // 4)
-    fill = eng.store.fill
+    stage = eng.store.stage
     misses = []
 
     def counted(ids):
         n0 = eng.external.stats.host_syncs
-        out = fill(ids)
+        out = stage(ids)
         assert eng.external.stats.host_syncs == n0
         misses.append(int((ids >= 0).sum()))
         return out
 
     def no_read(x, stats):
-        raise AssertionError("fill read the device")
+        raise AssertionError("stage read the device")
 
-    eng.store.fill = counted
+    eng.store.stage = counted
     monkeypatch.setattr(store_mod, "to_host", no_read)
     before = eng.snapshot_access_stats()
     got = eng.search(req)
     d = _delta(eng, before)
     n_phases = len(misses)
     assert n_phases == got.stats.n_db == want.stats.n_db > 0
-    assert d["host_syncs"] == want_d["host_syncs"] - 2 * n_phases
+    assert d["host_syncs"] == want_d["host_syncs"] - 3 * n_phases
     assert d["tier2_misses"] == sum(misses) == want_d["tier2_misses"]
     assert d["tier2_hits"] == want_d["tier2_hits"] > 0
 
@@ -233,9 +237,9 @@ def test_single_fill_drops_two_syncs_per_gathered_phase(small_dataset,
 @pytest.mark.parametrize("eviction", ["fifo", "lru"])
 def test_single_descent_reads_nothing_back(small_dataset, small_graph,
                                            eviction):
-    """A single-query search reads the miss count once a phase and the
-    miss ids once a gathered phase; between layers it reads nothing, and
-    it ends with one read of its counts and two of its answer."""
+    """A single-query search reads each phase program's miss list once;
+    between layers it reads nothing, and it ends with one read of its
+    counts and two of its answer."""
     X, Q = small_dataset
     eng = _engine(X, small_graph, len(X) // 4, eviction=eviction)
     assert small_graph.n_layers > 1
@@ -243,9 +247,9 @@ def test_single_descent_reads_nothing_back(small_dataset, small_graph,
         before = eng.snapshot_access_stats()
         eng.search(SearchRequest(query=q, k=10, ef=32))
         d = _delta(eng, before)
-        # each layer's phases: one per gathered phase, plus the last
+        # each layer's programs: one per gathered phase, plus its entry
         phases = d["n_db"] + small_graph.n_layers
-        assert d["host_syncs"] == phases + d["n_db"] + 3
+        assert d["host_syncs"] == phases + 3
 
 
 def test_fused_driver_leaves_the_phase_loop_counters(small_dataset,

@@ -1,16 +1,15 @@
 """The batched miss fill, ``TieredStore.fill_batch`` (DESIGN.md §5).
 
 The batched driver hands the store ids that its phase programs found
-absent from tier 2, and the store fills them on the device without
-looking them up again. Two contracts:
+absent from tier 2 (``stage_batch``), and its next phase program fills
+them on the device without looking them up again. Two contracts:
 
-1. **Parity** — a batched search through ``fill_batch`` returns the ids
-   and distances, and leaves the whole tier-2 ``CacheState``, EQUAL to
-   the same search through the general ``gather_batch`` path, after
+1. **Parity** — a batched search through the driver's fill returns the
+   ids and distances, and leaves the whole tier-2 ``CacheState``, EQUAL
+   to the same search through the general ``gather_batch`` path, after
    every batch.
-2. **Precondition** — every id the driver hands ``fill_batch`` is
-   absent from tier 2 at call time (the fact the dropped lookup rests
-   on).
+2. **Precondition** — every id the driver stages is absent from tier 2
+   at call time (the fact the dropped lookup rests on).
 """
 
 import jax
@@ -20,6 +19,7 @@ import pytest
 
 from repro.core.engine import EngineConfig, SearchRequest, WebANNSEngine
 from repro.core.store import TieredStore, cache_lookup
+from test_phase_loops import use_reference_loop
 
 # (precision, eviction, mode, tier-2 capacity as a share of N)
 CASES = {
@@ -44,8 +44,10 @@ def _engine(X, g, case):
     ))
 
 
-def _gather_batch_reference(store):
-    """Route the driver's fill through the general path it replaced."""
+def _gather_batch_reference(engine):
+    """Run the reference loop, its fill through the general path."""
+    use_reference_loop(engine)
+    store = engine.store
     store.fill_batch = lambda ids: jnp.asarray(store.gather_batch(ids))
 
 
@@ -60,15 +62,15 @@ def _assert_caches_equal(a, b):
 
 
 def _unions(store):
-    """Record each fill's union size, calling through to the real fill."""
+    """Record each fill's union size, calling through to the staging."""
     sizes = []
-    fill = store.fill_batch
+    stage = store.stage_batch
 
     def recorded(ids):
         sizes.append(len(np.unique(ids[ids >= 0])))
-        return fill(ids)
+        return stage(ids)
 
-    store.fill_batch = recorded
+    store.stage_batch = recorded
     return sizes
 
 
@@ -76,7 +78,7 @@ def _unions(store):
 def test_fill_batch_matches_gather_batch(small_dataset, small_graph, case):
     X, Q = small_dataset
     ref = _engine(X, small_graph, case)
-    _gather_batch_reference(ref.store)
+    _gather_batch_reference(ref)
     eng = _engine(X, small_graph, case)
     unions = _unions(eng.store)
     for batch in (Q[:6], Q[6:], Q[3:9]):  # the third batch meets warm rows
@@ -100,18 +102,18 @@ def test_every_filled_id_is_absent_from_tier2(small_dataset, small_graph,
     X, Q = small_dataset
     eng = _engine(X, small_graph, case)
     store = eng.store
-    fill = store.fill_batch
+    stage = store.stage_batch
     checked = []
 
-    def checked_fill(ids):
+    def checked_stage(ids):
         union = np.unique(ids[ids >= 0])
         present, _ = cache_lookup(store.cache,
                                   jnp.asarray(TieredStore._pad_pow2(union)))
         assert not np.asarray(present).any()
         checked.append(len(union))
-        return fill(ids)
+        return stage(ids)
 
-    store.fill_batch = checked_fill
+    store.stage_batch = checked_stage
     for batch in (Q[:6], Q[6:]):
         eng.search(SearchRequest(query=batch, k=10, ef=32))
     assert len(checked) > 1
