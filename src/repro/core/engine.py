@@ -12,11 +12,9 @@ The session API (DESIGN.md §6) is Index/Storage/Session layered:
 ``WebANNSEngine.open(path)`` reopens a saved :class:`repro.core.index.
 Index` (initialization-stage bulk load, one access per shard) over any
 :class:`repro.core.storage.StorageBackend`; ``engine.save(path)``
-persists the artifact; :meth:`WebANNSEngine.search` takes a typed
-:class:`SearchRequest` and returns a :class:`SearchResult`. (The
-pre-redesign tuple-returning ``query`` / ``query_batch`` shims were
-removed at their v0.6 milestone — ``search`` is the only query entry
-point.)
+persists the artifact; :meth:`WebANNSEngine.search`, the only query
+entry point, takes a typed :class:`SearchRequest` and returns a
+:class:`SearchResult`.
 
 Searches are FILTERABLE (DESIGN.md §9): ``SearchRequest.filter`` takes
 a :class:`repro.core.metadata.Filter` predicate (or one per query of a
@@ -351,13 +349,15 @@ def _gathered_phase(q, neighbors_l, state: S.SearchState, cache: CacheState,
 
 @functools.partial(jax.jit, static_argnames=("ef_upper",))
 def _descend(beam_ids, entry, walked, n_hops, n_dist, ef_upper: int):
-    """Next layer's (1,) entry, the first live id of the ``ef_upper``
-    best (else the old entry), and the running (hops, distances) of the
-    layers walked: the single-query descent reads nothing back."""
-    best = beam_ids[:ef_upper]
+    """Next layer's (1,) or (B, 1) entries, each the first live id of its
+    query's ``ef_upper`` best (else its old entry), and the running
+    (hops, distances) of the layers walked: the descent reads nothing
+    back."""
+    best = beam_ids[..., :ef_upper]
     live = best >= 0
-    nxt = jnp.where(live.any(), best[jnp.argmax(live)], entry[0])
-    return nxt[None], _tally(walked, n_hops, n_dist)
+    first = jnp.take_along_axis(best, jnp.argmax(live, -1)[..., None], -1)
+    return (jnp.where(live.any(-1, keepdims=True), first, entry),
+            _tally(walked, n_hops, n_dist))
 
 
 @jax.jit
@@ -466,7 +466,7 @@ class WebANNSEngine:
                 f"holds {self.n}"
             )
         self._miss_cap = self.config.ef_search + graph.max_degree + 1
-        # whole-batch accounting of the last query_batch call (DESIGN.md §5)
+        # whole-batch accounting of the last batch search (DESIGN.md §5)
         self.last_batch_stats: Optional[BatchStats] = None
         # ----- mutation lifecycle state (DESIGN.md §8) -----
         # tombstones: (N,) bool — deleted ids; never seeded/expanded/
@@ -939,17 +939,19 @@ class WebANNSEngine:
         return cfg.precision != "float32" and cfg.rerank_alpha > 0
 
     def _rerank_exact(
-        self, q: np.ndarray, ids: np.ndarray, dists: np.ndarray, k: int
+        self, Q: np.ndarray, ids: np.ndarray, dists: np.ndarray, k: int
     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Exact-rerank pass (DESIGN.md §7): re-score a candidate pool
+        """Exact-rerank pass (DESIGN.md §7): re-score B candidate pools
+        ((B, d) queries, (B, m) pools; one query as a batch of one)
         against full-precision tier-3 vectors in ONE counted access.
 
         The beam's distances were computed on dequantized tier-2 rows;
-        the pool (top k·α of the beam) is re-fetched from tier 3 —
-        bypassing the quantized cache — and exactly re-scored, so the
-        returned top-k order/distances match what a float32 cache would
-        have produced whenever the true k-th neighbor is inside the
-        pool. Quantized beam distances are kept only for invalid rows.
+        the pools (top k·α of each beam) are unioned, deduplicated and
+        re-fetched from tier 3 — bypassing the quantized cache — and
+        exactly re-scored, so the returned top-k order/distances match
+        what a float32 cache would have produced whenever the true k-th
+        neighbor is inside the pool. Quantized beam distances are kept
+        only when no pool holds a valid row.
 
         "Full precision" means tier 3's *stored* precision: if the index
         itself was persisted with ``save(precision="int8")``, fetches
@@ -957,27 +959,6 @@ class WebANNSEngine:
         payload, not the original corpus (keep float32 shards —
         ``save(precision="float32")`` — when tier-3 fidelity matters).
         """
-        ids = np.asarray(ids)
-        dists = np.asarray(dists)
-        valid = ids >= 0
-        if not valid.any():
-            return ids[:k], dists[:k]
-        with span("rerank"):
-            fetched = self.external.fetch(ids[valid])
-            self.external.mark_used_ids(ids[valid])
-            exact = np.full(ids.shape, np.inf, np.float32)
-            exact[valid] = _np_point_distance(
-                fetched, q, self.config.metric
-            )
-            order = np.argsort(exact, kind="stable")
-            return ids[order][:k], exact[order][:k]
-
-    def _rerank_exact_batch(
-        self, Q: np.ndarray, ids: np.ndarray, dists: np.ndarray, k: int
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """Batched exact-rerank: the B candidate pools are unioned and
-        deduplicated so the whole batch pays ONE tier-3 access (the same
-        amortization contract as the load phases, DESIGN.md §5)."""
         ids = np.asarray(ids)
         dists = np.asarray(dists)
         B, m = ids.shape
@@ -1018,41 +999,54 @@ class WebANNSEngine:
 
     def _lazy_layer(
         self, q: jnp.ndarray, layer: int, entry: jnp.ndarray, ef: int,
-        stats: QueryStats, eager: bool,
+        demand: Tuple[BatchStats, List[QueryStats]], eager: bool,
         banned: Optional[jnp.ndarray] = None,
     ) -> S.SearchState:
-        """Run one layer with phased lazy loading (or eager fetches),
-        from the device-resident (1,) ``entry``: one program and one read
-        of the whole miss list per phase (DESIGN.md §5)."""
+        """Run one layer with phased lazy loading (or eager fetches) for a
+        (d,) query or a (B, d) batch, from the device-resident (1,) or
+        (B, 1) ``entry``: one program and one read of the whole miss list
+        per phase, and ONE tier-3 access per phase for every list
+        (DESIGN.md §5). ``demand`` is the request's ``(BatchStats,
+        [QueryStats])``: each access, and each query's share of it."""
         cfg = self.config
         acc = self.external.stats
         store = self.store
+        batch, queries = demand
+        # a batch's lists go as one sorted union in a power-of-two bucket;
+        # one query's list keeps its order at its fixed length (§5)
+        stage = store.stage if q.ndim == 1 else store.stage_batch
         miss_cap = ef + self.graph.max_degree + 1
         # eager mode (webanns-base): trigger=1 → flush L after every miss
         trigger = 1 if eager else ef
         neighbors_l = self._layer_neighbors[layer]
         t0 = time.perf_counter()
         with span("seed"):
+            # an (N,) mask is broadcast over a batch inside the program
             store.cache, state = _enter_layer(
                 q, entry, neighbors_l, store.cache, self._tombs_device(),
                 self._noban_device() if banned is None else banned,
                 ef, miss_cap, cfg.metric, trigger, store.eviction,
             )
             miss_np = to_host(state.miss_ids, acc)
-        stats.t_in_mem += time.perf_counter() - t0
+        batch.t_in_mem += time.perf_counter() - t0
         for phase in range(1, cfg.max_phases + 1):  # 1 ran in the entry
-            # the list holds its misses first and -1 after them
-            mc = int((miss_np >= 0).sum())
-            acc.tier2_misses += mc
-            if mc == 0 or phase == cfg.max_phases:
+            # each list holds its misses first and -1 after them
+            mc = np.atleast_1d((miss_np >= 0).sum(-1))
+            n_miss = int(mc.sum())
+            acc.tier2_misses += n_miss
+            if n_miss == 0 or phase == cfg.max_phases:
                 break
-            # ONE tier-3 access for the whole lazy list (Alg. 1 line 24);
-            # the list goes whole, so no program is sized by mc
+            # ONE tier-3 access for every list (Alg. 1 line 24); the
+            # lists go whole, so no program is sized by the miss count
             with span("tier2_gather"):
                 db0, fetched0 = acc.n_db, acc.items_fetched
-                idx, rows = store.stage(miss_np)
-            stats.n_db += acc.n_db - db0
-            stats.items_fetched += acc.items_fetched - fetched0
+                idx, rows = stage(miss_np)
+            batch.n_db += acc.n_db - db0
+            batch.items_fetched += acc.items_fetched - fetched0
+            batch.n_phases += 1
+            for b in np.nonzero(mc)[0]:
+                queries[b].n_db += 1
+                queries[b].items_fetched += int(mc[b])
             t0 = time.perf_counter()
             with span("beam_phase"):
                 store.cache, state = _gathered_phase(
@@ -1060,68 +1054,8 @@ class WebANNSEngine:
                     cfg.metric, trigger, store.eviction,
                 )
                 miss_np = to_host(state.miss_ids, acc)
-            stats.t_in_mem += time.perf_counter() - t0
+            batch.t_in_mem += time.perf_counter() - t0
         return state
-
-    def _batched_lazy_layer(
-        self, Q: jnp.ndarray, layer: int, entry_ids: np.ndarray, ef: int,
-        per_stats: List[QueryStats], bstats: BatchStats, eager: bool,
-        banned: Optional[jnp.ndarray] = None,  # (B, N) per-query deny
-    ) -> S.SearchState:
-        """One layer of the batched phased-lazy driver (DESIGN.md §5).
-
-        All B queries advance one in-memory phase together (vmapped
-        against the same tier-2 snapshot); their miss lists are unioned,
-        deduplicated, and satisfied by ONE tier-3 access per phase for
-        the whole batch (``TieredStore.stage_batch``: every listed id
-        missed that same snapshot, so the store looks none of them up
-        again). The next program fills the union on the device and
-        scatters it back per query before the next phase.
-        """
-        cfg = self.config
-        acc = self.external.stats
-        store = self.store
-        miss_cap = ef + self.graph.max_degree + 1
-        trigger = 1 if eager else ef
-        neighbors_l = self._layer_neighbors[layer]
-
-        t0 = time.perf_counter()
-        with span("seed"):
-            # an (N,) mask is broadcast over the batch inside the program
-            store.cache, states = _enter_layer(
-                Q, entry_ids, neighbors_l, store.cache, self._tombs_device(),
-                self._noban_device() if banned is None else banned,
-                ef, miss_cap, cfg.metric, trigger, store.eviction,
-            )
-            miss_np = to_host(states.miss_ids, acc)
-        bstats.t_in_mem += time.perf_counter() - t0
-        for phase in range(1, cfg.max_phases + 1):  # 1 ran in the entry
-            mc = (miss_np >= 0).sum(-1)  # each list: misses, then -1
-            n_miss = int(mc.sum())
-            acc.tier2_misses += n_miss
-            if n_miss == 0 or phase == cfg.max_phases:
-                break
-            # ONE tier-3 access for the union of all B miss lists
-            with span("tier2_gather"):
-                db0 = acc.n_db
-                fetched0 = acc.items_fetched
-                idx, rows = store.stage_batch(miss_np)
-            bstats.n_db += acc.n_db - db0
-            bstats.items_fetched += acc.items_fetched - fetched0
-            bstats.n_phases += 1
-            # per-query demand: which queries needed this shared access
-            for b in np.nonzero(mc > 0)[0]:
-                per_stats[b].n_db += 1
-                per_stats[b].items_fetched += int(mc[b])
-            t0 = time.perf_counter()
-            with span("beam_phase"):
-                store.cache, states = _gathered_phase(
-                    Q, neighbors_l, states, store.cache, idx, rows,
-                    cfg.metric, trigger, store.eviction,
-                )
-                miss_np = to_host(states.miss_ids, acc)
-            bstats.t_in_mem += time.perf_counter() - t0
-        return states
 
     def _query_fused(
         self, q: np.ndarray, k: int, ef: int,
@@ -1181,22 +1115,22 @@ class WebANNSEngine:
         # apply the external-access cost model analytically
         stats.t_db = stats.n_db * cfg.t_setup \
             + stats.items_fetched * cfg.t_per_item
-        self.external.stats.n_db += stats.n_db
-        self.external.stats.items_fetched += stats.items_fetched
-        self.external.stats.items_used += stats.items_fetched  # lazy: R=0
-        self.external.stats.modeled_time += stats.t_db
+        acc = self.external.stats
+        acc.n_db += stats.n_db
+        acc.items_fetched += stats.items_fetched
+        acc.items_used += stats.items_fetched  # lazy: R=0
+        acc.modeled_time += stats.t_db
         stats.n_visited = stats.items_fetched  # lower bound (hits uncounted)
         if self._rerank_active():
-            db0 = self.external.stats.n_db
-            f0 = self.external.stats.items_fetched
-            m0 = self.external.stats.modeled_time
+            db0, f0, m0 = acc.n_db, acc.items_fetched, acc.modeled_time
             ids_np, dists_np = self._rerank_exact(
-                np.asarray(q), np.asarray(ids), np.asarray(dists), k
+                np.asarray(q)[None], np.asarray(ids)[None],
+                np.asarray(dists)[None], k,
             )
-            stats.n_db += self.external.stats.n_db - db0
-            stats.items_fetched += self.external.stats.items_fetched - f0
-            stats.t_db += self.external.stats.modeled_time - m0
-            return ids_np, dists_np, stats
+            stats.n_db += acc.n_db - db0
+            stats.items_fetched += acc.items_fetched - f0
+            stats.t_db += acc.modeled_time - m0
+            return ids_np[0], dists_np[0], stats
         return np.asarray(ids), np.asarray(dists), stats
 
     def _search_one(
@@ -1204,7 +1138,7 @@ class WebANNSEngine:
         filt: Optional[Filter] = None,
         boost: bool = True,
     ) -> Tuple[np.ndarray, np.ndarray, QueryStats]:
-        """Single-query driver body. Returns (ids, dists, stats).
+        """One-query front end. Returns (ids, dists, stats).
 
         ``filt`` restricts results via route-but-don't-return masking
         (DESIGN.md §9): traversal is IDENTICAL to an unfiltered run at
@@ -1229,75 +1163,149 @@ class WebANNSEngine:
             banned = jnp.asarray(banned_np)
         if cfg.fused and cfg.mode == "webanns":
             return self._query_fused(q, k, ef, banned=banned)
+        ids, dists, (stats,), _ = self._lazy_search(q, k, ef, banned)
+        return ids, dists, stats
+
+    def _lazy_search(
+        self, q: np.ndarray, k: int, ef: int,
+        banned: Optional[jnp.ndarray],
+    ) -> Tuple[np.ndarray, np.ndarray, List[QueryStats], BatchStats]:
+        """The phase-loop search of a (d,) query or a (B, d) batch
+        (DESIGN.md §5) → (ids, dists, [QueryStats], BatchStats). The
+        entries and the counts stay on the device between layers: a
+        layer costs its phases alone, however many layers the graph
+        drew, and the counts are read once, in finalize."""
+        cfg = self.config
         eager = cfg.mode == "webanns-base"
         acc = self.external.stats
-        misses0 = acc.tier2_misses
-        stats = QueryStats()
-        qj = jnp.asarray(q, jnp.float32)
-        t_db0 = acc.modeled_time
-        entry = jnp.asarray([self.graph.entry_point], jnp.int32)
-        walked = jnp.zeros((2,), jnp.int32)  # (hops, distances)
+        misses0, t_db0 = acc.tier2_misses, acc.modeled_time
+        lead = q.shape[:-1]  # () for one query, (B,) for a batch
+        B = int(np.prod(lead))
+        demand = (BatchStats(batch_size=B), [QueryStats() for _ in range(B)])
+        batch, queries = demand
+        # the query, entries and counts go up as copies, not programs
+        qj = jnp.asarray(q)
+        entry = jnp.asarray(np.full(lead + (1,), self.graph.entry_point,
+                                    np.int32))
+        walked = jnp.asarray(np.zeros((2,) + lead, np.int32))  # hops, dists
         # upper layers: beam of ef_upper (greedy for 1), lazily loaded too;
-        # the deny mask is irrelevant here (descent only routes). The
-        # entry and the counts stay on the device: a layer costs its
-        # phases alone, however many layers the graph drew.
+        # the deny mask is irrelevant here (descent only routes)
         for lc in range(self.graph.max_level, 0, -1):
-            st = self._lazy_layer(qj, lc, entry, cfg.ef_upper, stats, eager)
+            st = self._lazy_layer(qj, lc, entry, cfg.ef_upper, demand, eager)
             with span("descend"):
                 entry, walked = _descend(
                     st.beam.ids, entry, walked, st.n_hops, st.n_dist,
                     cfg.ef_upper,
                 )
         st = self._lazy_layer(
-            qj, 0, entry, max(ef, k), stats, eager, banned=banned
+            qj, 0, entry, max(ef, k), demand, eager, banned=banned
         )
         with span("finalize"):
-            hops, n_dist = to_host(_tally(walked, st.n_hops, st.n_dist), acc)
-            stats.n_hops += int(hops)
-            stats.n_dist += int(n_dist)
-            stats.n_visited = stats.n_dist  # every visited id gets a distance
-            if self._rerank_active():
-                pool = min(st.beam.ef, quant.rerank_pool(k, cfg.rerank_alpha))
-                if filt is not None:
-                    # allowed-only pool: a banned id must never reach the
-                    # rerank fetch, let alone the returned top-k
-                    p_dists, p_ids = _finalize_cached(st, pool)
-                else:
-                    p_ids = st.beam.ids[:pool]
-                    p_dists = st.beam.dists[:pool]
-                db0, f0 = acc.n_db, acc.items_fetched
-                ids, dists = self._rerank_exact(
-                    q, to_host(p_ids, acc), to_host(p_dists, acc), k,
-                )
-                stats.n_db += acc.n_db - db0
-                stats.items_fetched += acc.items_fetched - f0
-            elif filt is not None:
-                f_dists, f_ids = _finalize_cached(st, k)
-                ids, dists = to_host(f_ids, acc), to_host(f_dists, acc)
-            else:
-                ids = to_host(st.beam.ids[:k], acc)
-                dists = to_host(st.beam.dists[:k], acc)
-        acc.tier2_hits += stats.n_dist - (acc.tier2_misses - misses0)
-        stats.t_db = acc.modeled_time - t_db0
-        return ids, dists, stats
+            ids, dists = self._finalize(q, st, walked, k, banned is not None,
+                                        demand, t_db0)
+        acc.tier2_hits += sum(s.n_dist for s in queries) \
+            - (acc.tier2_misses - misses0)
+        return ids, dists, queries, batch
 
-    def _normalize_filters(
-        self, filt, B: int
-    ) -> Optional[List[Optional[Filter]]]:
-        """Request-level filter → per-query list (length B) or None."""
-        if filt is None:
-            return None
-        if isinstance(filt, Filter):
-            return [filt] * B
-        filters = list(filt)
-        if len(filters) != B:
-            raise ValueError(
-                f"{len(filters)} filters for a batch of {B} queries — "
-                "pass one Filter (broadcast) or exactly one per query"
+    def _finalize(
+        self, q: np.ndarray, state: S.SearchState, walked: jnp.ndarray,
+        k: int, filtered: bool,
+        demand: Tuple[BatchStats, List[QueryStats]], t_db0: float,
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """The reads that end the search of a (d,) query or a (B, d)
+        batch: each query's (hops, distances) over every layer, in one
+        read, then its top-k on the host. A quantized search reranks
+        each beam's head exactly, from an allowed-only pool under a
+        filter, so a banned id never reaches the rerank fetch (§9); a
+        filtered one re-extracts the allowed top-k. The rerank's access
+        is the request's; each query of a batch demanded it, and a single
+        query is charged what it fetched. ``demand`` then takes the
+        modeled tier-3 time since ``t_db0``, and each query its share.
+        """
+        cfg = self.config
+        acc = self.external.stats
+        batch, queries = demand
+        counts = to_host(_tally(walked, state.n_hops, state.n_dist), acc)
+        if self._rerank_active():
+            pool = min(state.beam.ids.shape[-1],
+                       quant.rerank_pool(k, cfg.rerank_alpha))
+            if filtered:
+                p_dists, p_ids = _finalize_cached(state, pool)  # lint: disable=R003 -- pool ≤ k·α with the beam width grain-snapped in _boost_ef; bounded trace set
+            else:
+                p_ids = state.beam.ids[..., :pool]
+                p_dists = state.beam.dists[..., :pool]
+            db0, f0 = acc.n_db, acc.items_fetched
+            ids, dists = self._rerank_exact(
+                q.reshape(-1, q.shape[-1]),
+                to_host(p_ids, acc).reshape(-1, pool),
+                to_host(p_dists, acc).reshape(-1, pool), k,
             )
-        if all(f is None for f in filters):
-            return None
-        return filters
+            ids = ids.reshape(q.shape[:-1] + (-1,))
+            dists = dists.reshape(q.shape[:-1] + (-1,))
+            batch.n_db += acc.n_db - db0
+            batch.items_fetched += acc.items_fetched - f0
+            if q.ndim == 1:
+                queries[0].n_db += acc.n_db - db0
+                queries[0].items_fetched += acc.items_fetched - f0
+            else:
+                for s in queries:
+                    s.n_db += 1
+        elif filtered:
+            f_dists, f_ids = _finalize_cached(state, k)
+            ids, dists = to_host(f_ids, acc), to_host(f_dists, acc)
+        else:
+            ids = to_host(state.beam.ids[..., :k], acc)
+            dists = to_host(state.beam.dists[..., :k], acc)
+        batch.t_db = acc.modeled_time - t_db0
+        for s, hops, n_dist in zip(queries, *counts.reshape(2, -1)):
+            s.n_hops += int(hops)
+            s.n_dist += int(n_dist)
+            s.n_visited = s.n_dist  # every visited id gets a distance
+            s.t_in_mem = batch.t_in_mem / len(queries)
+            s.t_db = batch.t_db / len(queries)
+        return ids, dists
+
+    def _batch_filter(
+        self, filt, B: int, ef: int
+    ) -> Tuple[Optional[List[Optional[Filter]]], Optional[jnp.ndarray], int]:
+        """A batch's request filter → (one Filter or None per query, the
+        deny mask on the device, the batch's one effective ef); with no
+        filter, ``(None, None, ef)``.
+
+        A broadcast Filter compiles ONCE to an (N,) mask, which the
+        programs broadcast over the batch (no (B, N) host copy);
+        per-query filters compile to a (B, N) matrix whose unfiltered
+        rows deny nothing. The batch shares ONE effective ef (a jitted
+        phase has one static beam width), so the widest per-query boost
+        wins — every batch driver uses it, keeping loop/batched parity
+        exact (DESIGN.md §9).
+        """
+        if filt is None:
+            return None, None, ef
+        if isinstance(filt, Filter):
+            filters, compiled = [filt] * B, [self._compile_filter(filt)]
+        else:
+            filters = list(filt)
+            if len(filters) != B:
+                raise ValueError(
+                    f"{len(filters)} filters for a batch of {B} queries — "
+                    "pass one Filter (broadcast) or exactly one per query"
+                )
+            if all(f is None for f in filters):
+                return None, None, ef
+            compiled = [None if f is None else self._compile_filter(f)
+                        for f in filters]
+        ef_eff = ef
+        for c in compiled:  # (deny mask, live selectivity) or None
+            if c is not None and c[1] > 0.0:
+                ef_eff = max(ef_eff, self._boost_ef(ef, c[1]))
+        if isinstance(filt, Filter):
+            return filters, jnp.asarray(compiled[0][0]), ef_eff
+        mask = np.zeros((B, self.n), bool)
+        for b, c in enumerate(compiled):
+            if c is not None:
+                mask[b] = c[0]
+        return filters, jnp.asarray(mask), ef_eff
 
     # ------------------------------------------- mesh-sharded driver (§10)
 
@@ -1323,7 +1331,7 @@ class WebANNSEngine:
             self._shard_rt = rt = (mesh, state)
         return rt
 
-    def _sharded_layer(self, Qj, layer: int, entry: np.ndarray, ef: int):
+    def _sharded_layer(self, Qj, layer: int, entry: jnp.ndarray, ef: int):
         """One layer as one shard_map program → (beam ids/dists/explored,
         n_hops, n_dist), all replicated (B, ...) arrays."""
         from repro.core import distributed as dshard
@@ -1339,112 +1347,58 @@ class WebANNSEngine:
 
     def _sharded_many(
         self, Q: np.ndarray, k: int, ef: int,
-        shared_banned: Optional[np.ndarray],
-        banned_rows: Optional[List[Optional[np.ndarray]]],
+        banned: Optional[jnp.ndarray],
     ) -> Tuple[np.ndarray, np.ndarray, List[QueryStats]]:
         """Mesh-sharded batch driver body (DESIGN.md §10).
 
         Every layer runs as ONE shard_map program — beam phase per shard
         against its device-resident rows, candidates merged by the fused
-        cross-shard top-k — while all host logic (entry propagation,
-        filter masks, exact rerank, finalize) is copied verbatim from
-        the single-device batched driver, so (ids, dists) come back
-        bit-identical to that driver run WARM — each shard's slab is
-        100% resident, and a cold lazy driver's expansion order is
-        cache-state-dependent (tests/test_sharded_parity.py docstring
-        spells out the protocol). Traversal performs
-        ZERO tier-3 accesses (each shard's slab is 100% resident, the
-        fused-path memory model); only the exact-rerank pass fetches.
-        With no host phase loop, ``tier2_hits``, ``tier2_misses`` and
-        ``host_syncs`` do not move.
+        cross-shard top-k — while the descent, the deny mask, finalize
+        and the exact rerank are the phase loops' own, so (ids, dists)
+        come back bit-identical to the batched driver run WARM (each
+        shard's slab is 100% resident; a cold lazy driver's expansion
+        order is cache-state-dependent — see the protocol in
+        tests/test_sharded_parity.py). Traversal performs ZERO tier-3
+        accesses; only the exact-rerank pass fetches. With no host phase
+        loop, ``tier2_hits`` and ``tier2_misses`` do not move;
+        ``host_syncs`` counts finalize's reads.
         """
         cfg = self.config
         B = len(Q)
-        bstats = BatchStats(batch_size=B)
-        per_stats = [QueryStats() for _ in range(B)]
+        demand = (BatchStats(batch_size=B), [QueryStats() for _ in range(B)])
+        bstats, per_stats = demand
         Qj = jnp.asarray(Q)
-        banned_mat = None
-        if shared_banned is not None:
-            banned_mat = jnp.asarray(shared_banned)
-        elif banned_rows is not None:
-            banned_np = np.zeros((B, self.n), bool)
-            for b, row in enumerate(banned_rows):
-                if row is not None:
-                    banned_np[b] = row
-            banned_mat = jnp.asarray(banned_np)
         t_db0 = self.external.stats.modeled_time
-        entry = np.full((B, 1), self.graph.entry_point, np.int32)
+        entry = jnp.asarray(np.full((B, 1), self.graph.entry_point, np.int32))
+        walked = jnp.asarray(np.zeros((2, B), np.int32))  # hops, dists
+        t0 = time.perf_counter()
         for lc in range(self.graph.max_level, 0, -1):
-            t0 = time.perf_counter()
-            bi, bd, be, hops_a, ndist_a = self._sharded_layer(
+            bi, _, _, hops, n_dist = self._sharded_layer(
                 Qj, lc, entry, cfg.ef_upper
             )
-            bi.block_until_ready()
-            bstats.t_in_mem += time.perf_counter() - t0
-            best = np.asarray(bi[:, : cfg.ef_upper])
-            hops = np.asarray(hops_a)
-            ndist = np.asarray(ndist_a)
-            for b in range(B):
-                row = best[b][best[b] >= 0]
-                if len(row):
-                    entry[b, 0] = row[0]
-                per_stats[b].n_hops += int(hops[b])
-                per_stats[b].n_dist += int(ndist[b])
-        t0 = time.perf_counter()
-        bi, bd, be, hops_a, ndist_a = self._sharded_layer(
+            entry, walked = _descend(bi, entry, walked, hops, n_dist,
+                                     cfg.ef_upper)
+        bi, bd, be, hops, n_dist = self._sharded_layer(
             Qj, 0, entry, max(ef, k)
         )
         bi.block_until_ready()
-        bstats.t_in_mem += time.perf_counter() - t0
-        hops = np.asarray(hops_a)
-        ndist = np.asarray(ndist_a)
-        # adapt the final beam to the finalize/rerank plumbing shared
-        # with the single-device drivers (only beam + banned are read)
+        bstats.t_in_mem = time.perf_counter() - t0
+        # adapt the final beam to the finalize plumbing shared with the
+        # single-device drivers (only beam, banned and counts are read)
         st = S.SearchState(
             beam=S.Beam(ids=bi, dists=bd, explored=be),
             visited=jnp.zeros((1, 1), bool),
             banned=jnp.broadcast_to(
-                self._noban_device() if banned_mat is None else banned_mat,
+                self._noban_device() if banned is None else banned,
                 (B, self.n),
             ),
             miss_ids=jnp.zeros((1, 1), jnp.int32),
             miss_count=jnp.zeros((1,), jnp.int32),
-            n_hops=hops_a,
-            n_dist=ndist_a,
+            n_hops=hops,
+            n_dist=n_dist,
         )
-        if self._rerank_active():
-            # ONE shared tier-3 access reranks the whole batch (§5/§7)
-            pool = min(int(bi.shape[1]),
-                       quant.rerank_pool(k, cfg.rerank_alpha))
-            if banned_mat is not None:
-                p_dists, p_ids = _finalize_cached(st, pool)  # lint: disable=R003 -- pool ≤ k·α with the beam width grain-snapped in _boost_ef; bounded trace set
-            else:
-                p_ids = bi[:, :pool]
-                p_dists = bd[:, :pool]
-            db0 = self.external.stats.n_db
-            f0 = self.external.stats.items_fetched
-            ids, dists = self._rerank_exact_batch(
-                Q, np.asarray(p_ids), np.asarray(p_dists), k,
-            )
-            bstats.n_db += self.external.stats.n_db - db0
-            bstats.items_fetched += (
-                self.external.stats.items_fetched - f0
-            )
-            for b in range(B):  # every query demanded the shared rerank
-                per_stats[b].n_db += 1
-        elif banned_mat is not None:
-            f_dists, f_ids = _finalize_cached(st, k)
-            ids, dists = np.asarray(f_ids), np.asarray(f_dists)
-        else:
-            ids = np.asarray(bi[:, :k])
-            dists = np.asarray(bd[:, :k])
-        bstats.t_db = self.external.stats.modeled_time - t_db0
-        for b in range(B):
-            per_stats[b].n_hops += int(hops[b])
-            per_stats[b].n_dist += int(ndist[b])
-            per_stats[b].n_visited = per_stats[b].n_dist
-            per_stats[b].t_in_mem = bstats.t_in_mem / B
-            per_stats[b].t_db = bstats.t_db / B
+        ids, dists = self._finalize(Q, st, walked, k, banned is not None,
+                                    demand, t_db0)
         self.last_batch_stats = bstats
         return ids, dists, per_stats
 
@@ -1452,7 +1406,7 @@ class WebANNSEngine:
         self, Q: np.ndarray, k: int, ef: Optional[int], batch_mode: str,
         filt=None,
     ) -> Tuple[np.ndarray, np.ndarray, List[QueryStats]]:
-        """Batch driver body (DESIGN.md §5). Returns (ids, dists, stats).
+        """Batch front end (DESIGN.md §5). Returns (ids, dists, stats).
 
         ``batch_mode="batched"`` (default) runs the cross-query amortized
         driver: one jit dispatch per phase for the whole batch and one
@@ -1474,54 +1428,24 @@ class WebANNSEngine:
             return (np.full((B, k), -1, np.int32),
                     np.full((B, k), np.inf, np.float32),
                     [QueryStats() for _ in range(B)])
-        # per-query filters compile to one (B, N) deny matrix — or, for
-        # a single broadcast Filter, ONE (N,) mask compiled once and
-        # broadcast on device. The batch shares ONE effective ef (a
-        # jitted phase has one static beam width), so the widest
-        # per-query boost wins — both drivers use it, keeping
-        # loop/batched parity exact (DESIGN.md §9)
-        filters = self._normalize_filters(filt, B)
-        banned_rows: Optional[List[Optional[np.ndarray]]] = None
-        shared_banned: Optional[np.ndarray] = None
-        if filters is not None:
-            if isinstance(filt, Filter):  # broadcast: compile ONCE
-                shared_banned, sel = self._compile_filter(filt)
-                banned_rows = [shared_banned] * B  # loop fallback rows
-                if sel > 0.0:
-                    ef = max(ef, self._boost_ef(ef, sel))
-            else:
-                banned_rows = []
-                ef_eff = ef
-                for f in filters:
-                    if f is None:
-                        banned_rows.append(None)
-                        continue
-                    banned_np, sel = self._compile_filter(f)
-                    banned_rows.append(banned_np)
-                    if sel > 0.0:
-                        ef_eff = max(ef_eff, self._boost_ef(ef, sel))
-                ef = ef_eff
+        filters, banned, ef = self._batch_filter(filt, B, ef)
         # mesh-sharded driver (DESIGN.md §10): takes precedence over the
         # fused single-device reroute — sharded search is itself fully
         # in-graph with device-resident per-shard payload
         if (cfg.n_shards > 1 and cfg.mode == "webanns"
                 and batch_mode == "batched"):
-            return self._sharded_many(Q, k, ef, shared_banned, banned_rows)
+            return self._sharded_many(Q, k, ef, banned)
         # fused engines run the whole query as one program (_query_fused);
         # the batched host driver would silently reroute them, so honor
         # cfg.fused via the sequential path until a fused batch exists
         if cfg.fused and cfg.mode == "webanns" and batch_mode == "batched":
             batch_mode = "loop"
         if batch_mode == "loop":
-            out_i, out_d, out_s = [], [], []
-            for b, q in enumerate(Q):
-                i, d, s = self._search_one(
-                    q, k, ef, filt=None if filters is None else filters[b],
-                    boost=False,
-                )
-                out_i.append(i)
-                out_d.append(d)
-                out_s.append(s)
+            out_i, out_d, out_s = zip(*(
+                self._search_one(q, k, ef, boost=False,
+                                 filt=None if filters is None else filters[b])
+                for b, q in enumerate(Q)
+            ))
             self.last_batch_stats = BatchStats(
                 batch_size=B,
                 n_db=sum(s.n_db for s in out_s),
@@ -1529,87 +1453,14 @@ class WebANNSEngine:
                 t_in_mem=sum(s.t_in_mem for s in out_s),
                 t_db=sum(s.t_db for s in out_s),
             )
-            return np.stack(out_i), np.stack(out_d), out_s
+            return np.stack(out_i), np.stack(out_d), list(out_s)
         if batch_mode != "batched":
             raise ValueError(
                 f"batch_mode must be 'batched' or 'loop', got {batch_mode!r}"
             )
-        eager = cfg.mode == "webanns-base"
-        acc = self.external.stats
-        misses0 = acc.tier2_misses
-        bstats = BatchStats(batch_size=B)
-        per_stats = [QueryStats() for _ in range(B)]
-        Qj = jnp.asarray(Q)
-        banned_mat = None
-        if shared_banned is not None:
-            # (N,) once — batch_make_state broadcasts on device (a view,
-            # not a (B, N) host materialization)
-            banned_mat = jnp.asarray(shared_banned)
-        elif banned_rows is not None:
-            banned_np = np.zeros((B, self.n), bool)
-            for b, row in enumerate(banned_rows):
-                if row is not None:
-                    banned_np[b] = row
-            banned_mat = jnp.asarray(banned_np)
-        t_db0 = acc.modeled_time
-        entry = np.full((B, 1), self.graph.entry_point, np.int32)
-        for lc in range(self.graph.max_level, 0, -1):
-            st = self._batched_lazy_layer(
-                Qj, lc, entry, cfg.ef_upper, per_stats, bstats, eager
-            )
-            with span("descend"):
-                best = to_host(st.beam.ids[:, : cfg.ef_upper], acc)
-                hops = to_host(st.n_hops, acc)
-                ndist = to_host(st.n_dist, acc)
-                for b in range(B):
-                    row = best[b][best[b] >= 0]
-                    if len(row):
-                        entry[b, 0] = row[0]
-                    per_stats[b].n_hops += int(hops[b])
-                    per_stats[b].n_dist += int(ndist[b])
-        st = self._batched_lazy_layer(
-            Qj, 0, entry, max(ef, k), per_stats, bstats, eager,
-            banned=banned_mat,
+        ids, dists, per_stats, self.last_batch_stats = self._lazy_search(
+            Q, k, ef, banned
         )
-        with span("finalize"):
-            hops = to_host(st.n_hops, acc)
-            ndist = to_host(st.n_dist, acc)
-            if self._rerank_active():
-                # ONE shared tier-3 access reranks the whole batch (§5/§7)
-                pool = min(int(st.beam.ids.shape[1]),
-                           quant.rerank_pool(k, cfg.rerank_alpha))
-                if banned_mat is not None:
-                    # per-query allowed-only pools: banned ids never reach
-                    # the rerank fetch (route-but-don't-return, §9)
-                    p_dists, p_ids = _finalize_cached(st, pool)  # lint: disable=R003 -- pool ≤ k·α with the beam width grain-snapped in _boost_ef; bounded trace set
-                else:
-                    p_ids = st.beam.ids[:, :pool]
-                    p_dists = st.beam.dists[:, :pool]
-                db0, f0 = acc.n_db, acc.items_fetched
-                ids, dists = self._rerank_exact_batch(
-                    Q, to_host(p_ids, acc), to_host(p_dists, acc), k,
-                )
-                bstats.n_db += acc.n_db - db0
-                bstats.items_fetched += acc.items_fetched - f0
-                for b in range(B):  # every query demanded the shared rerank
-                    per_stats[b].n_db += 1
-            elif banned_mat is not None:
-                f_dists, f_ids = _finalize_cached(st, k)
-                ids, dists = to_host(f_ids, acc), to_host(f_dists, acc)
-            else:
-                ids = to_host(st.beam.ids[:, :k], acc)
-                dists = to_host(st.beam.dists[:, :k], acc)
-        bstats.t_db = acc.modeled_time - t_db0
-        for b in range(B):
-            per_stats[b].n_hops += int(hops[b])
-            per_stats[b].n_dist += int(ndist[b])
-            per_stats[b].n_visited = per_stats[b].n_dist
-            # amortized per-query share of the batch's wall/model time
-            per_stats[b].t_in_mem = bstats.t_in_mem / B
-            per_stats[b].t_db = bstats.t_db / B
-        acc.tier2_hits += sum(s.n_dist for s in per_stats) \
-            - (acc.tier2_misses - misses0)
-        self.last_batch_stats = bstats
         return ids, dists, per_stats
 
     # ------------------------------------------------- typed session API

@@ -127,6 +127,28 @@ def test_per_query_demand_vs_batch_accounting(small_dataset, small_graph,
     assert all(s.n_dist > 0 for s in stats)
 
 
+def test_per_query_stats_follow_their_queries(small_dataset, small_graph,
+                                              overlap_queries):
+    """Each query's answer and counts are its own: rolling the batch
+    rolls them with it (each phase stages the sorted union of the miss
+    lists, so tier 2 evolves the same in either order)."""
+    X, _ = small_dataset
+    cap = max(16, len(X) // 10)
+    runs = []
+    for Q in (overlap_queries, np.roll(overlap_queries, 1, axis=0)):
+        ids, dists, stats = tuple_query_batch(
+            _fresh(X, small_graph, cap), Q, k=10, ef=48
+        )
+        runs.append((ids, dists, [
+            (s.n_hops, s.n_dist, s.n_db, s.items_fetched) for s in stats
+        ]))
+    (i1, d1, s1), (i2, d2, s2) = runs
+    np.testing.assert_array_equal(np.roll(i1, 1, axis=0), i2)
+    np.testing.assert_array_equal(np.roll(d1, 1, axis=0), d2)
+    assert s2 == s1[-1:] + s1[:-1]
+    assert len(set(s1)) > 1  # the counts tell the queries apart
+
+
 # ------------------------------------------------- batched store primitives
 
 

@@ -16,7 +16,7 @@ from repro.core.cache_opt import (
     optimize_memory_size,
     simulate_n_db,
 )
-from repro.core.engine import EngineConfig, WebANNSEngine
+from repro.core.engine import EngineConfig, SearchRequest, WebANNSEngine
 
 
 def test_eq3_random_fetch_closed_form():
@@ -145,8 +145,8 @@ def test_algorithm2_end_to_end_on_engine(small_dataset, small_graph):
         eng.warm_cache()
         stats = []
         for q in Q[:4]:
-            _, _, s = eng.query(q, k=10, ef=48)
-            stats.append(s)
+            res = eng.search(SearchRequest(query=q, k=10, ef=48))
+            stats.append(res.stats)
         n_db = float(np.mean([s.n_db for s in stats]))
         n_q = float(np.mean([s.n_visited for s in stats]))
         t_q = float(np.mean([s.t_query for s in stats]))

@@ -234,16 +234,17 @@ def test_single_fill_drops_two_syncs_per_gathered_phase(small_dataset,
     assert d["tier2_hits"] == want_d["tier2_hits"] > 0
 
 
+@pytest.mark.parametrize("batch", [1, 4], ids=["b1", "b4"])
 @pytest.mark.parametrize("eviction", ["fifo", "lru"])
-def test_single_descent_reads_nothing_back(small_dataset, small_graph,
-                                           eviction):
-    """A single-query search reads each phase program's miss list once;
-    between layers it reads nothing, and it ends with one read of its
-    counts and two of its answer."""
+def test_descent_reads_nothing_back(small_dataset, small_graph, eviction,
+                                    batch):
+    """A search of one query or of a batch reads each phase program's
+    miss list once; between layers it reads nothing, and it ends with
+    one read of its counts and two of its answer."""
     X, Q = small_dataset
     eng = _engine(X, small_graph, len(X) // 4, eviction=eviction)
     assert small_graph.n_layers > 1
-    for q in Q[:3]:
+    for q in (Q[:3] if batch == 1 else [Q[i:i + batch] for i in (0, 4, 8)]):
         before = eng.snapshot_access_stats()
         eng.search(SearchRequest(query=q, k=10, ef=32))
         d = _delta(eng, before)
